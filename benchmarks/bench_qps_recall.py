@@ -17,9 +17,10 @@ Two sweeps ride along for the sharded-serving PR:
 
   * an expansion-width (E) sweep at the serving beam, isolating the
     multi-expansion knob from the beam knob,
-  * a sharded SPMD sweep over 1/2/4/8 simulated devices — each point
-    runs in a SUBPROCESS with ``--xla_force_host_platform_device_count``
-    (the flag must be set before jax initializes), builds the same
+  * a sharded SPMD sweep over 1/2/4/8 devices — on CPU each point runs
+    in a SUBPROCESS with ``--xla_force_host_platform_device_count`` (the
+    flag must be set before jax initializes); on a TPU host the points
+    run in this process over its chips — and each builds the same
     deterministic index, and serves through the mesh-sharded
     ``ShardedServingIndex`` (replicate-to-all router, halo shards,
     cross-shard merge); rows record recall parity vs the parent's
@@ -65,20 +66,20 @@ def _shard_params() -> PiPNNParams:
                        leaf=LeafParams(k=2), max_deg=32, seed=0)
 
 
-def _sharded_child(ndev: int) -> dict:
-    """One sharded sweep point: runs inside a subprocess whose XLA_FLAGS
-    forced ``ndev`` host devices.  Prints nothing; returns the record."""
+def _sharded_point(ndev: int) -> dict:
+    """One sharded sweep point over the first ``ndev`` devices of this
+    process.  Prints nothing; returns the record."""
     import jax
 
     from benchmarks.common import dataset, ground_truth, timed
     from jax.sharding import Mesh
     from repro.core.serving import ServingIndex
 
-    assert len(jax.devices()) == ndev, (len(jax.devices()), ndev)
+    assert len(jax.devices()) >= ndev, (len(jax.devices()), ndev)
     x, q = dataset(N, D)
     truth = ground_truth(N, D)
     idx = pipnn.build(x, _shard_params())
-    mesh = Mesh(np.array(jax.devices()), ("shards",))
+    mesh = Mesh(np.array(jax.devices()[:ndev]), ("shards",))
     ssv = ServingIndex.from_index(idx, x, mesh=mesh)
     fn = lambda: ssv.search(q, k=10, beam=SHARD_BEAM, expansions=4)
     ids, _ = timed(fn)                        # warm-up/compile
@@ -95,9 +96,19 @@ def _sharded_child(ndev: int) -> dict:
 
 
 def _run_sharded_sweep() -> list[dict]:
-    """Spawn one subprocess per device count (the forced-host-device flag
-    must precede jax init) and collect the records; a failed point is
-    recorded with its error rather than sinking the bench."""
+    """The sharded sweep points; a failed point fails the run.
+
+    On a TPU the points run in this process over the host's own chips
+    (a child process could not reach a chip this process holds), up to
+    the number of chips it has.  On CPU each point is a subprocess whose
+    ``--xla_force_host_platform_device_count`` forces its device count
+    (the flag must precede jax init)."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        have = len(jax.devices())
+        return [_sharded_point(ndev) for ndev in SHARD_DEVICES
+                if ndev <= have]
     out = []
     for ndev in SHARD_DEVICES:
         env = dict(os.environ)
@@ -109,9 +120,8 @@ def _run_sharded_sweep() -> list[dict]:
              "--sharded-child", str(ndev)],
             capture_output=True, text=True, env=env, timeout=1200)
         if proc.returncode != 0:
-            out.append({"engine": "serve_sharded", "ndev": ndev,
-                        "error": proc.stderr.strip()[-300:]})
-            continue
+            raise RuntimeError(f"sharded sweep point ndev={ndev} failed: "
+                               f"{proc.stderr.strip()[-300:]}")
         out.append(json.loads(proc.stdout.strip().splitlines()[-1]))
     return out
 
@@ -233,11 +243,6 @@ def run() -> list[Row]:
             r_single = r                         # sharded-parity reference
     # ---- sharded SPMD sweep (subprocess per simulated device count) ----
     for rec in _run_sharded_sweep():
-        if "error" in rec:
-            rows.append((f"qps_recall/pipnn_1rep/sharded_ndev{rec['ndev']}",
-                         0.0, f"ERROR {rec['error'][:80]}"))
-            records.append({"index": "pipnn_1rep", **rec})
-            continue
         rec["recall_delta_vs_single"] = round(r_single - rec["recall"], 4)
         rows.append((
             f"qps_recall/pipnn_1rep/sharded_ndev{rec['ndev']}"
@@ -258,7 +263,7 @@ if __name__ == "__main__":
     # with XLA_FLAGS forcing NDEV host devices (set before jax init).
     if "--sharded-child" in sys.argv:
         n = int(sys.argv[sys.argv.index("--sharded-child") + 1])
-        print(json.dumps(_sharded_child(n)))
+        print(json.dumps(_sharded_point(n)))
         sys.exit(0)
     for row in run():
         print(",".join(str(c) for c in row))

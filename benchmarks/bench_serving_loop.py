@@ -20,7 +20,8 @@ tracked across PRs:
     straggler) against the sharded loop; records healthy vs degraded
     recall, unhandled-error count (must be 0) and shard re-admission.
     Runs in a subprocess because ``--xla_force_host_platform_device_count``
-    must precede jax init; skipped with ``--no-sharded``.
+    must precede jax init; skipped with ``--no-sharded``, which a TPU
+    host requires (a child cannot reach the chips the parent holds).
 
   PYTHONPATH=src python benchmarks/bench_serving_loop.py --smoke
 """
@@ -233,7 +234,10 @@ print(json.dumps(fault_drill()))
 """
 
 
-def fault_drill_subprocess() -> dict | None:
+def fault_drill_subprocess() -> dict:
+    """The fault drill in a child process that forces 8 CPU host devices.
+    Never called on a TPU host: the parent holds the chips there (``main``
+    refuses first).  A failed child fails the run."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     root = pathlib.Path(__file__).resolve().parent.parent
@@ -242,9 +246,7 @@ def fault_drill_subprocess() -> dict | None:
                           env=env, capture_output=True, text=True,
                           timeout=1200)
     if proc.returncode != 0:
-        print(proc.stderr[-2000:], file=sys.stderr)
-        return {"bench": "fault_drill", "unhandled_errors": 1,
-                "error": "child failed"}
+        raise RuntimeError(f"fault-drill child failed:\n{proc.stderr[-2000:]}")
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
@@ -261,6 +263,14 @@ def main(argv=None) -> int:
     ap.add_argument("--no-sharded", action="store_true",
                     help="skip the 8-device fault-drill subprocess")
     args = ap.parse_args(argv)
+    if not args.no_sharded:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise SystemExit("the fault drill runs on 8 forced CPU host "
+                             "devices in a child process, which cannot reach "
+                             "the chips this process holds; pass "
+                             "--no-sharded on a TPU host")
 
     x, q = dataset(args.n, args.d, seed=args.seed)
     rng = np.random.default_rng(args.seed + 1)
@@ -278,9 +288,8 @@ def main(argv=None) -> int:
     print(json.dumps(rec))
     if not args.no_sharded:
         rec = fault_drill_subprocess()
-        if rec is not None:
-            records.append(rec)
-            print(json.dumps(rec))
+        records.append(rec)
+        print(json.dumps(rec))
     append_bench_json(records, path=BENCH_SERVING_JSON,
                       bench="serving_loop_smoke", n=args.n, d=args.d,
                       requests=args.requests)

@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import use_compile_cache
+
 BENCHES = [
     "build",            # Fig. 1 / Fig. 5 build times
     "qps_recall",       # Fig. 5 QPS-recall curves
@@ -39,6 +41,7 @@ def main(argv=None) -> int:
         print("\n".join(BENCHES))
         return 0
     selected = [b for b in args.only.split(",") if b] or BENCHES
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     n_fail = 0

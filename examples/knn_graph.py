@@ -8,6 +8,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.knn_graph import knn_graph_pipnn, knn_graph_recall
 from repro.core.leaf import LeafParams
 from repro.core.pipnn import PiPNNParams
@@ -16,6 +17,7 @@ from repro.data.pipeline import VectorPipelineConfig, make_vectors
 
 
 def main():
+    use_compile_cache()
     x = make_vectors(VectorPipelineConfig(n=8192, dim=32, n_clusters=32,
                                           seed=1))
     params = PiPNNParams(

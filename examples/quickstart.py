@@ -6,6 +6,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import pipnn
 from repro.core.beam_search import brute_force_knn, recall_at_k
 from repro.core.leaf import LeafParams
@@ -15,6 +16,7 @@ from repro.data.pipeline import VectorPipelineConfig, make_queries, make_vectors
 
 
 def main():
+    use_compile_cache()
     # 1. data: 16k Gaussian-mixture vectors, 200 held-out queries
     cfg = VectorPipelineConfig(n=16384, dim=48, n_clusters=64, seed=0)
     x = make_vectors(cfg)

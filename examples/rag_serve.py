@@ -15,12 +15,14 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.serve import RETRIEVER_DTYPES, Retriever, Server
 
 DOC_LEN = 16
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--requests", type=int, default=8)

@@ -8,10 +8,12 @@ import argparse
 import shutil
 import tempfile
 
+from repro.compile_cache import use_compile_cache
 from repro.launch import train
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--steps", type=int, default=200)

@@ -280,7 +280,7 @@ def hashprune_merge_flat(
 def merge_segmented_edges(res_ids, res_hashes, res_dists,
                           src, dst, hashes, dists, *,
                           use_pallas: bool = False,
-                          interpret: bool = True) -> Reservoir:
+                          interpret: bool | None = None) -> Reservoir:
     """Segmented fold of a flat candidate-edge chunk into a reservoir.
 
     ``merge_flat_edges`` re-expresses the whole [n, l_max] reservoir as a
@@ -304,14 +304,18 @@ def merge_segmented_edges(res_ids, res_hashes, res_dists,
     ``kernels/segmented_merge.py`` kernel (rank-based merge of two sorted
     rows + cross-reservoir bucket dedup, no sort); the fallback is the
     per-row ``hashprune_batch`` sort.  Traceable either way — the streaming
-    chunk step and the SPMD tile step inline it.
+    chunk step and the SPMD tile step inline it.  ``interpret=None`` runs
+    the kernel compiled on TPU and interpreted elsewhere.
     """
     n, l_max = res_ids.shape
     chunk_res = hashprune_flat(src, dst, hashes, dists,
                                n_points=n, l_max=l_max)
     if use_pallas:
+        from repro.kernels.ops import default_interpret
         from repro.kernels.segmented_merge import merge_sorted_reservoirs
 
+        if interpret is None:
+            interpret = default_interpret()
         return merge_sorted_reservoirs(
             res_ids, res_hashes, res_dists,
             chunk_res.ids, chunk_res.hashes, chunk_res.dists,
@@ -340,7 +344,7 @@ def hashprune_merge_segmented(
     dists: jax.Array,
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Reservoir:
     """Donating jit wrapper over ``merge_segmented_edges``.
 

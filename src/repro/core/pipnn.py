@@ -26,8 +26,8 @@ Two Stage-2+3 execution strategies, selected by ``build(..., streaming=)``:
     ``inverted``) or the all-to-all ``robust_prune`` leaf method — emits
     candidate edges as fixed-shape device arrays
     (``leaf.emit_knn_edges_jax`` / ``leaf.emit_robust_prune_edges_jax``),
-    computes residual hashes from the precomputed sketches (Pallas
-    ``edge_hashes`` on TPU, ``hash_from_sketches`` fallback elsewhere), and
+    computes residual hashes from the precomputed sketches (the fused jnp
+    ``hash_from_sketches``; the Pallas ``edge_hashes`` on request), and
     folds the chunk into the persistent [n, l_max] reservoir with buffer
     donation.  The fold is the SEGMENTED merge by default
     (``PiPNNParams.merge``): one global sort over the chunk's own edges
@@ -55,6 +55,11 @@ Stage 4 (``robust_prune.final_prune``) is device-resident too: a donated
 end, so with ``streaming=True`` the entire Stage 2-4 pipeline performs no
 per-chunk host syncs.
 
+Last, ``connect_from_start`` adds the few edges that make every point
+reachable from the entry point (the medoid): on well-separated clusters
+no leaf spans two clusters, and without them beam search would only ever
+reach the medoid's own cluster.  ``stats["connect_edges"]`` counts them.
+
 All paths are bit-identical by HashPrune's mergeability (Theorem 3.1):
 tests assert equal graphs on both metrics, for both the segmented and flat
 folds, and streaming-vs-host final_prune.
@@ -75,6 +80,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import time
 from typing import Any, Callable
 
@@ -90,9 +96,12 @@ from repro.core.leaf import (EdgeList, LeafParams, _leaf_robust_prune,
                              build_leaf_edges, emit_knn_edges_jax,
                              emit_robust_prune_edges_jax, iter_leaf_id_chunks,
                              leaf_knn_jax)
-from repro.core.rbc import (RBCParams, leaves_to_padded, padded_coverage,
-                            partition_padded, resolve_execution)
+from repro.core.rbc import (RBCParams, _pairwise_np, leaves_to_padded,
+                            padded_coverage, partition_padded,
+                            resolve_execution)
 from repro.core.robust_prune import final_prune
+
+logger = logging.getLogger(__name__)
 
 _KNN_METHODS = ("bidirected", "directed", "inverted")
 _STREAM_METHODS = _KNN_METHODS + ("robust_prune",)
@@ -117,7 +126,8 @@ class PiPNNParams:
     max_deg: int = 64          # final graph degree cap (paper's comparison deg)
     metric: str = "l2"
     seed: int = 0
-    use_pallas_hash: bool | None = None  # None: auto (Pallas on TPU only)
+    use_pallas_hash: bool | None = None  # None: auto (the fused jnp hash;
+    #                            see _resolve_pallas)
     merge: str = "segmented"   # streaming reservoir fold: "segmented" folds
     #                            each chunk via a chunk-only sort + bounded
     #                            per-row merge; "flat" is the global-re-sort
@@ -153,18 +163,28 @@ class PiPNNIndex:
 
 
 def _resolve_pallas(params: PiPNNParams) -> tuple[bool, bool, bool]:
-    """(use_pallas_hash, use_pallas_merge, interpret) for the Pallas kernels."""
+    """(use_pallas_hash, use_pallas_merge, interpret) for the Pallas kernels.
+
+    Auto mode runs the Pallas reservoir merge on TPU, but never the Pallas
+    edge hash: that kernel reads the two [E, m] sketch gathers from HBM,
+    where XLA stores them lane-padded from m to 128 columns.  At an
+    auto-sized SIFT1M stream chunk (E = 64M edges) each gather is 32.8 GB
+    and the TPU compiler refuses the step (RESOURCE_EXHAUSTED); the jnp
+    ``hash_from_sketches`` fuses gather and hash, so [E, m] never exists."""
     on_tpu = jax.default_backend() == "tpu"
-    use_hash = (on_tpu if params.use_pallas_hash is None
-                else bool(params.use_pallas_hash))
+    use_hash = bool(params.use_pallas_hash)
     use_merge = (on_tpu if params.use_pallas_merge is None
                  else bool(params.use_pallas_merge))
+    logger.info("build kernels: edge hash=%s, reservoir merge=%s%s",
+                "pallas" if use_hash else "jnp",
+                "pallas" if use_merge else "jnp",
+                "" if on_tpu else " (interpreted)")
     return use_hash, use_merge, not on_tpu
 
 
 def _hash_edges(
     edges: EdgeList, sketches: np.ndarray, *,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool, interpret: bool,
 ) -> np.ndarray:
     """Residual hashes h_src(dst) for every candidate edge, via sketches."""
     h = _sketch.edge_hashes_from_ids(
@@ -357,6 +377,88 @@ def _build_reservoir_streaming(
     return Reservoir(ids=ids_r, hashes=hs_r, dists=ds_r), n_edges, mem
 
 
+# ---------------------------------------------------------------------------
+# Reachability from the entry point
+# ---------------------------------------------------------------------------
+
+_CONNECT_ROUNDS = 16
+
+
+def _reachable(graph: np.ndarray, roots, blocked: np.ndarray | None = None
+               ) -> np.ndarray:
+    """bool [n]: the points reachable from ``roots`` along graph edges
+    without entering a ``blocked`` point."""
+    n = graph.shape[0]
+    seen = np.zeros(n, bool) if blocked is None else blocked.copy()
+    new = np.zeros(n, bool)
+    front = np.asarray(roots, np.int64)
+    seen[front] = new[front] = True
+    while front.size:
+        nb = np.unique(graph[front].ravel())
+        nb = nb[nb >= 0]
+        nb = nb[~seen[nb]]
+        seen[nb] = new[nb] = True
+        front = nb
+    return new
+
+
+def connect_from_start(graph: np.ndarray, dists: np.ndarray | None,
+                       x: np.ndarray, start: int, metric: str
+                       ) -> tuple[np.ndarray, np.ndarray | None, int]:
+    """Add the edges that make every point reachable from ``start``.
+
+    RBC sends each point to its ``fanout`` nearest leaders, so on data
+    whose clusters are well separated (more leaders per cluster than the
+    fanout) no leaf spans two clusters and the graph splits into one
+    component per cluster: beam search from the medoid then reaches its
+    own cluster only.  Each round takes the unreached points' source
+    components (greedily: the smallest unreached id and everything it
+    reaches) and adds one edge into each — from ``start`` while its row
+    has free slots, largest components first, so the entry point's first
+    expansion fans out to every cluster; otherwise from the nearest
+    reached point, into a free slot when it has one.  Returns (graph,
+    dists, edges added); the inputs are not modified, and ``dists=None``
+    (a packing that keeps no edge lengths) stays None.
+    """
+    graph = graph.copy()
+    dists = None if dists is None else dists.copy()
+    reached = _reachable(graph, [start])
+    added = 0
+    for _ in range(_CONNECT_ROUNDS):
+        if reached.all():
+            return graph, dists, added
+        seen, roots, sizes = reached.copy(), [], []
+        while not seen.all():
+            u = int(np.argmin(seen))
+            comp = _reachable(graph, [u], seen)
+            roots.append(u)
+            sizes.append(int(comp.sum()))
+            seen |= comp
+        roots = np.asarray(roots)[np.argsort(-np.asarray(sizes),
+                                             kind="stable")]
+        n_hub = min(roots.size, int(np.sum(graph[start] < 0)))
+        srcs = [start] * n_hub
+        open_rows = np.flatnonzero(reached & (graph < 0).any(axis=1))
+        pool = open_rows if open_rows.size else np.flatnonzero(reached)
+        for u in roots[n_hub:]:
+            d = _pairwise_np(x[u][None], x[pool], metric)[0]
+            srcs.append(int(pool[np.argmin(d)]))
+        for v, u in zip(srcs, roots):
+            free = np.flatnonzero(graph[v] < 0)
+            slot = free[0] if free.size else graph.shape[1] - 1
+            graph[v, slot] = u
+            if dists is not None:
+                dists[v, slot] = _pairwise_np(x[v][None], x[u][None],
+                                              metric)[0, 0]
+        added += roots.size
+        reached = _reachable(graph, [start])
+    if not reached.all():
+        raise RuntimeError(f"{int((~reached).sum())} points still unreachable "
+                           f"from the entry point after {_CONNECT_ROUNDS} "
+                           "linking rounds")
+    return graph, dists, added
+
+
 def build(
     x: np.ndarray,
     params: PiPNNParams | None = None,
@@ -479,12 +581,19 @@ def build(
             ds = np.pad(ds, ((0, 0), (0, pad)), constant_values=np.inf)
         graph, dists = ids, ds
     timings["final_prune"] = time.perf_counter() - t0
+
+    # --- every point reachable from the entry point ------------------------
+    t0 = time.perf_counter()
+    start = medoid(x, seed=params.seed)
+    graph, dists, stats["connect_edges"] = connect_from_start(
+        np.asarray(graph), np.asarray(dists), x, start, params.metric)
+    timings["connect"] = time.perf_counter() - t0
     timings["total"] = sum(timings.values())
 
     return PiPNNIndex(
         graph=graph,
         dists=dists,
-        start=medoid(x, seed=params.seed),
+        start=start,
         params=params,
         timings=timings,
         stats=stats,

@@ -62,21 +62,24 @@ def edge_hashes_from_ids(
     dst: jax.Array,        # [E] int32 edge destinations
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Residual hashes h_src(dst) [E] int32 for a flat edge list.
 
     Gathers the two sketch rows per edge and packs the sign bits, either
     through the fused Pallas kernel (``use_pallas=True``; ``interpret``
-    selects the CPU fallback executor) or the pure-jnp
-    ``hash_from_sketches``.  Both produce identical int32 hashes.
+    runs it in the interpreter, and ``None`` does so only off-TPU) or the
+    pure-jnp ``hash_from_sketches``.  Both produce identical int32 hashes.
     Traceable: the streaming build calls this inside its fused chunk step.
     """
     s_sk = sketches[jnp.maximum(src, 0)]
     d_sk = sketches[jnp.maximum(dst, 0)]
     if use_pallas:
         from repro.kernels.edge_hash import edge_hashes  # no core->kernels cycle
+        from repro.kernels.ops import default_interpret
 
+        if interpret is None:
+            interpret = default_interpret()
         return edge_hashes(s_sk, d_sk, interpret=interpret)
     return hash_from_sketches(d_sk, s_sk)
 

@@ -55,7 +55,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import metrics as _metrics
-from repro.distributed.compat import shard_map_norep
 
 ROUTERS = ("all", "leaders")
 
@@ -324,13 +323,17 @@ class ShardedServingIndex:
         ghost rows so no graph edge is dropped; ``halo=False`` keeps the
         bare induced subgraph (smaller, lower recall).  Each shard's
         entry point is its OWNED member nearest the global entry
-        ``x[start]``.  ``dtype`` follows the single-device packing:
+        ``x[start]``, and every row of a shard is made reachable from it
+        (``pipnn.connect_from_start`` on the shard's local graph): the
+        edges that link the global graph's components hang off the global
+        entry point, which a shard without it does not hold.  ``dtype`` follows the single-device packing:
         ``None``/f32, a downcast dtype (e.g. bf16), or ``"int8"`` for
         the scalar-quantized copy (quantization is per-point/row-local,
         so sharding cannot change the bits — a ghost row quantizes
         identically in every shard that holds it).
         """
         from repro.core.leader_assign import leader_assign
+        from repro.core.pipnn import connect_from_start
         from repro.core.serving import _is_int8
 
         if len(mesh.axis_names) != 1:
@@ -391,6 +394,14 @@ class ShardedServingIndex:
         else:
             xp = x
             pts_s = np.zeros((s, m, d), np.float32)
+        # per-shard entry: the OWNED member nearest the global entry point
+        # (owned rows come first, so the argmin's position IS its local id)
+        dstart = _dist_to_point(x, x[start], metric)
+        starts_local = np.zeros(s, np.int32)
+        for i in range(s):
+            mem = rows[i][: owned[i]]
+            if len(mem):
+                starts_local[i] = np.argmin(dstart[mem])
         lookup = np.full(n, -1, np.int64)
         for i, ridx in enumerate(rows):
             c = len(ridx)
@@ -401,7 +412,12 @@ class ShardedServingIndex:
             # member rows: every edge endpoint is in-shard by halo
             # construction; ghost rows keep whichever of their own edges
             # happen to land in-shard
-            graph_s[i, :c] = np.where(ga >= 0, lookup[np.maximum(ga, 0)], -1)
+            local = np.where(ga >= 0, lookup[np.maximum(ga, 0)], -1)
+            if c:
+                local, _, _ = connect_from_start(
+                    local.astype(np.int32), None, x[ridx],
+                    int(starts_local[i]), metric)
+            graph_s[i, :c] = local
             norms_s[i, :c] = norms[ridx]
             pts_s[i, :c] = xp[ridx]
             if int8:
@@ -409,14 +425,6 @@ class ShardedServingIndex:
         pts_j = jnp.asarray(pts_s)
         if dtype is not None and not int8:
             pts_j = pts_j.astype(dtype)
-        # per-shard entry: the OWNED member nearest the global entry point
-        # (owned rows come first, so the argmin's position IS its local id)
-        dstart = _dist_to_point(x, x[start], metric)
-        starts_local = np.zeros(s, np.int32)
-        for i in range(s):
-            mem = rows[i][: owned[i]]
-            if len(mem):
-                starts_local[i] = np.argmin(dstart[mem])
         # commit every stacked array to its mesh placement NOW: shard-axis
         # arrays split over the devices, router leaders replicated.  A
         # plain jnp.asarray would land everything on device 0 and the jit
@@ -476,10 +484,10 @@ class ShardedServingIndex:
             return gid[None], ds[None], hops[None], comps[None], conv[None]
 
         p, rep = P(self.axis), P()
-        sm = shard_map_norep(
+        sm = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(p, p, p, p, p, p, rep),
-            out_specs=(p, p, p, p, p))
+            out_specs=(p, p, p, p, p), check_vma=False)
         fn = jax.jit(sm)
         self._search_cache[key] = fn
         return fn

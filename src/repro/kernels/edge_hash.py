@@ -6,14 +6,15 @@ p, dst = candidate c), computes the paper's Eq. 1 hash
     h_p(c) = pack_bits( sign(Sketch(c) - Sketch(p)) )
 
 in one VPU pass: subtract, threshold, weighted-sum with powers of two.
-Edges are viewed as [rows, 128] so tiles are lane-aligned; m <= 16 bits pack
-into an int32 (stored alongside the 8-byte reservoir slot layout the paper
-describes).
+Edges arrive as [_BLOCK, m] row blocks and leave as one lane-dense
+[1, _BLOCK] hash row per grid step; m <= 16 bits pack into an int32
+(stored alongside the 8-byte reservoir slot layout the paper describes).
 
-Wired into both PiPNN build paths via ``sketch.edge_hashes_from_ids``: the
-streaming build fuses it into the per-chunk jitted step, the flat path uses
-it when ``PiPNNParams.use_pallas_hash`` is set (auto-on on TPU, with the
-pure-jnp ``hash_from_sketches`` as the interpret-mode fallback).
+Wired into both PiPNN build paths via ``sketch.edge_hashes_from_ids`` when
+``PiPNNParams.use_pallas_hash`` is set.  The auto default is the fused jnp
+``hash_from_sketches`` on every backend: this kernel needs its [E, m]
+inputs materialized (lane-padded) in HBM, which does not fit a chip at a
+SIFT1M stream chunk (``pipnn._resolve_pallas``).
 """
 from __future__ import annotations
 
@@ -23,15 +24,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+_BLOCK = 1024  # edges per grid step (8 lane tiles)
 
 
-def _edge_hash_kernel(src_ref, dst_ref, o_ref, *, m: int):
-    s = src_ref[0]                              # [LANE, m]
-    t = dst_ref[0]                              # [LANE, m]
-    bits = ((t - s) >= 0.0).astype(jnp.int32)   # [LANE, m]
-    weights = (2 ** jax.lax.broadcasted_iota(jnp.int32, (LANE, m), 1))
-    o_ref[0] = jnp.sum(bits * weights, axis=1)  # [LANE]
+def _edge_hash_kernel(src_ref, dst_ref, o_ref):
+    s = src_ref[...]                            # [_BLOCK, m]
+    t = dst_ref[...]
+    bits = ((t - s) >= 0.0).astype(jnp.int32)   # [_BLOCK, m]
+    weights = jax.lax.shift_left(
+        jnp.int32(1), jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1))
+    o_ref[...] = jnp.sum(bits * weights, axis=1)[None, :]   # [1, _BLOCK]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -41,29 +43,28 @@ def edge_hashes(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Packed residual hashes [E] int32."""
+    """Packed residual hashes [E] int32.  The hashes leave the kernel as
+    one lane-dense [1, E] row (a [1, _BLOCK] block per grid step)."""
     e, m = src_sketch.shape
     if m > 16:
         raise ValueError(f"m={m} hash bits do not pack into the paper's "
                          "16-bit reservoir slot")
     if e == 0:
         return jnp.zeros((0,), jnp.int32)
-    pad = (-e) % LANE
+    pad = (-e) % _BLOCK
     if pad:
         src_sketch = jnp.pad(src_sketch, ((0, pad), (0, 0)))
         dst_sketch = jnp.pad(dst_sketch, ((0, pad), (0, 0)))
-    rows = src_sketch.shape[0] // LANE
-    s3 = src_sketch.reshape(rows, LANE, m)
-    t3 = dst_sketch.reshape(rows, LANE, m)
+    ep = src_sketch.shape[0]
     out = pl.pallas_call(
-        functools.partial(_edge_hash_kernel, m=m),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
-        grid=(rows,),
+        _edge_hash_kernel,
+        out_shape=jax.ShapeDtypeStruct((1, ep), jnp.int32),
+        grid=(ep // _BLOCK,),
         in_specs=[
-            pl.BlockSpec((1, LANE, m), lambda r: (r, 0, 0)),
-            pl.BlockSpec((1, LANE, m), lambda r: (r, 0, 0)),
+            pl.BlockSpec((_BLOCK, m), lambda r: (r, 0)),
+            pl.BlockSpec((_BLOCK, m), lambda r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((1, LANE), lambda r: (r, 0)),
+        out_specs=pl.BlockSpec((1, _BLOCK), lambda r: (0, r)),
         interpret=interpret,
-    )(s3, t3)
-    return out.reshape(-1)[:e]
+    )(src_sketch, dst_sketch)
+    return out[0, :e]

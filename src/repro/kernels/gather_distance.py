@@ -1,40 +1,42 @@
 """Pallas TPU kernel: fused neighbor gather + distance block for serving.
 
 The multi-expansion beam search's per-step hot loop: given the ``E*R``
-neighbor ids each query just expanded, gather their vectors and compute the
-``[Q_tile, E*R]`` dissimilarity block in one pass.  Grid over query tiles;
-per step the kernel
+neighbor ids each query just expanded, gather their vectors and compute
+the ``[Q, E*R]`` dissimilarity block.  One kernel body
+(``_gather_ip_kernel``) serves all four public entries — f32 or int8
+points, VMEM-resident (``gather_distance[_int8]``, shards that pass
+``fits_vmem``) or left in HBM (``gather_distance[_int8]_hbm``,
+``MemorySpace.ANY``).  Grid over query tiles; per step the kernel
 
-  * loads a ``[TQ, d]`` query tile and its ``[TQ, C]`` neighbor-id tile
-    into VMEM,
-  * gathers the ``TQ*C`` neighbor rows from the VMEM-resident points block,
-  * contracts queries against their gathered neighbors as a batched
-    matvec on the MXU (``dot_general`` with a batch dim, f32 accumulation),
-  * fuses the norm expansion using the PRECOMPUTED f32 point norms
-    (``metrics.point_norms`` — computed before any points-dtype downcast,
-    so a bf16 serving copy only rounds the inner-product term),
-  * writes +inf for ``-1``-padded ids.
+  * takes the tile's ``[TQ, C]`` candidate ids as an SMEM block (DMA
+    addresses are scalars),
+  * brings the candidates' rows into a double-buffered VMEM scratch by
+    per-row ``make_async_copy`` DMAs, 128 candidates per wave, the next
+    wave in flight while the current one is reduced,
+  * returns the inner products ``<query, row>``: f32 elementwise product
+    + lane reduction, or exact int32 for int8 points against the query
+    quantized with the packing's own scheme.
 
-The points block is replicated to every grid step, so the compiler keeps
-one VMEM-resident copy: this kernel targets serving shards whose points
-fit VMEM (``fits_vmem``).  Larger shards use the HBM-streaming twins
-(``gather_distance_hbm`` / ``gather_distance_int8_hbm``): points stay in
-HBM (``TPUMemorySpace.ANY``) and each query row's neighbor rows arrive in
-VMEM scratch via double-buffered ``pltpu.make_async_copy`` DMAs — while
-row ``t`` computes its distances the row ``t+1`` copies are already in
-flight.  The serving engine's kernel-path resolution
+The wrappers apply the norm expansion with the PRECOMPUTED f32 point
+norms (``metrics.point_norms``, computed before any downcast or
+quantization) in the op order of the ``kernels.ref`` oracles, and write
++inf for ``-1``-padded ids.  The serving engine's kernel-path resolution
 (``beam_search.resolve_kernel_path``) selects vmem vs hbm per shard size
-instead of silently dropping to the XLA gather
-(``kernels.ref.gather_distance_ref``), which remains the CPU path.  All
-four kernels are interpret-mode tested against their oracles on CPU.
+on TPU; the XLA gather (``kernels.ref.gather_distance_ref``) is the CPU
+path.  All four entries are interpret-mode tested against their oracles
+on CPU and compiled for a described v5e by ``tests/test_tpu_compile.py``.
 
-``gather_distance_int8`` is the scalar-quantized twin (paper Sec. 6:
+Mosaic limits that shape the kernel: a 32-bit row can be DMA'd alone,
+but a packed (int8/bf16) array is tiled in 8-row groups whose rows share
+32-bit words, so a packed candidate fetches its whole tile (1 KiB for
+int8 at d=128) and its row is unpacked in registers; rows can only be
+sliced out of arrays whose lane-padded width is 128 (d <= 128).
+
+``gather_distance_int8`` is the scalar-quantized path (paper Sec. 6:
 "quantized GEMM operations on scalar-quantized points"): int8 points +
-per-point f32 scales packed by ``ServingIndex(dtype="int8")``, int8 x int8
--> int32 batched matvec on the MXU, fused rescale + exact-norm expansion.
-The 4x-smaller points block means ``fits_vmem`` admits shards 4x larger
-before HBM streaming is needed — and once it is, the int8 packing also
-cuts the streamed DMA bytes 4x per row.
+per-point f32 scales packed by ``ServingIndex(dtype="int8")``; the 4x
+smaller points block lets ``fits_vmem`` admit shards 4x larger before
+HBM streaming is needed.
 
 The VMEM points budget is configurable: ``fits_vmem(budget=...)`` per
 call (``ServingIndex(vmem_budget=...)`` threads it through), or the
@@ -57,7 +59,10 @@ from repro.kernels.tiling import LANE, padded_bytes
 
 logger = logging.getLogger(__name__)
 _TQ = 8  # query rows per grid step (f32 sublane tile)
-_SUBLANE_I8 = 32  # int8 sublane tile: the packed points block pads rows to 32
+_CB = LANE  # candidate rows per DMA wave (one output lane tile)
+# rows of one packed (int8/bf16) memory tile: the smallest row slice
+# Mosaic can DMA out of a packed points array
+_PACKED_TILE_ROWS = 8
 
 # points bytes budget for auto-enabling the VMEM-resident kernel (leave
 # headroom out of ~16 MB/core for the query/id/output tiles)
@@ -116,30 +121,9 @@ def fits_vmem(points: jax.Array, *extras: jax.Array,
     return total <= int(budget)
 
 
-def _gather_distance_kernel(q_ref, ids_ref, pts_ref, n2_ref, o_ref, *,
-                            metric: str):
-    q = q_ref[...].astype(jnp.float32)          # [TQ, d]
-    ids = ids_ref[...]                          # [TQ, C]
-    tq, c = ids.shape
-    flat = jnp.maximum(ids.reshape(-1), 0)      # [TQ*C]
-    g = jnp.take(pts_ref[...], flat, axis=0).astype(jnp.float32)
-    g = g.reshape(tq, c, -1)                    # [TQ, C, d]
-    # batched matvec on the MXU: contract d, batch over the query row
-    ip = jax.lax.dot_general(
-        q, g, (((1,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )                                           # [TQ, C]
-    if metric == "mips":
-        d = -ip
-    else:
-        n2 = jnp.take(n2_ref[...].reshape(-1), flat).reshape(tq, c)
-        if metric == "cosine":
-            qn = jnp.sqrt(jnp.sum(q * q, axis=-1))
-            d = 1.0 - ip / jnp.maximum(qn[:, None] * n2, 1e-30)
-        else:
-            q2 = jnp.sum(q * q, axis=-1)
-            d = jnp.maximum(q2[:, None] + n2 - 2.0 * ip, 0.0)
-    o_ref[...] = jnp.where(ids >= 0, d, jnp.inf)
-
+# ---------------------------------------------------------------------------
+# The shared row-gather inner-product kernel
+# ---------------------------------------------------------------------------
 
 def _pad(x, axis, mult, value):
     pad = (-x.shape[axis]) % mult
@@ -149,6 +133,189 @@ def _pad(x, axis, mult, value):
     w[axis] = (0, pad)
     return jnp.pad(x, w, constant_values=value)
 
+
+def _unpack_rows(words, sub, pack: int):
+    """Recover each candidate's own row from the packed 32-bit words of
+    its DMA'd tile.  ``words``: [_CB, dp] int32, word-row ``sub // pack``
+    of the candidate's tile already selected; ``sub``: [_CB, 1] row index
+    within the tile.  int8 rows come back sign-extended to int32, bf16
+    rows as their exact f32 values."""
+    k = sub % pack
+    if pack == 4:
+        return jax.lax.shift_right_arithmetic(
+            jax.lax.shift_left(words, 24 - 8 * k), jnp.int32(24))
+    hi = jax.lax.shift_left(words, 16 - 16 * k) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(hi, jnp.float32)
+
+
+def _gather_ip_kernel(ids_ref, q_ref, pts_ref, o_ref, buf, sub_ref, sem, *,
+                      pack: int):
+    """One grid step: ``tq`` query rows x ``cp`` candidate ids (SMEM).
+
+    The candidate rows arrive ``_CB`` at a time through per-row DMAs into
+    a double-buffered VMEM scratch: while wave ``s`` is reduced, wave
+    ``s+1``'s copies are in flight.  A 32-bit row is one DMA; a packed
+    int8/bf16 row lives interleaved with its tile-mates in 32-bit words
+    (Mosaic cannot slice a packed memref below its 8-row tile), so such
+    a candidate fetches its whole 8-row tile and the row is unpacked in
+    registers (``_unpack_rows``).  -1 ids fetch row 0; the caller masks
+    them."""
+    tq, cp = ids_ref.shape
+    nb = o_ref.shape[0]
+    tile = 1 if pack == 1 else _PACKED_TILE_ROWS
+    wrows = 1 if pack == 1 else tile // pack     # word rows per DMA
+    src = pts_ref if pack == 1 else pts_ref.bitcast(jnp.int32)
+
+    def each_copy(t, j, slot, fn):
+        def one(c, carry):
+            sid = jnp.maximum(ids_ref[t, j * _CB + c], 0)
+            copy = pltpu.make_async_copy(
+                src.at[pl.ds(sid // tile * wrows, wrows), :],
+                buf.at[slot, pl.ds(c * wrows, wrows), :],
+                sem.at[slot])
+            fn(c, sid, copy)
+            return carry
+
+        jax.lax.fori_loop(0, _CB, one, 0)
+
+    def start(t, j, slot):
+        def fn(c, sid, copy):
+            copy.start()
+            if pack > 1:
+                sub_ref[slot, pl.ds(c, 1), :] = jnp.full(
+                    (1, LANE), sid % tile, jnp.int32)
+        each_copy(t, j, slot, fn)
+
+    def wait(t, j, slot):
+        each_copy(t, j, slot, lambda c, sid, copy: copy.wait())
+
+    def row(t, carry):
+        # wave (t, j) is number t * nb + j; waves alternate buffer slots.
+        # j is static and selects a whole [tq, _CB] output tile, so each
+        # store has one dynamic (sublane) offset — Mosaic refuses a row
+        # store at a dynamic sublane into a block wider than one tile
+        for j in range(nb):
+            slot = (t * nb + j) % 2
+            if j + 1 < nb:
+                start(t, j + 1, 1 - slot)
+            else:
+                @pl.when(t + 1 < tq)
+                def _prefetch_next_row():
+                    start(t + 1, 0, 1 - slot)
+
+            wait(t, j, slot)
+            if pack == 1:
+                g = buf[slot].astype(jnp.float32)           # [_CB, dp]
+            else:
+                sub = sub_ref[slot][:, :1]                  # [_CB, 1]
+                words = buf[slot, pl.ds(0, _CB, stride=wrows), :]
+                for w in range(1, wrows):
+                    words = jnp.where(
+                        sub // pack == w,
+                        buf[slot, pl.ds(w, _CB, stride=wrows), :], words)
+                g = _unpack_rows(words, sub, pack)
+            q = q_ref[pl.ds(t, 1), :].astype(g.dtype)       # [1, dp]
+            # elementwise product + lane reduction: the f32 oracle
+            # (``ref.gather_distance_hbm_ref``) reduces the same padded
+            # extent the same way; int32 accumulation is exact
+            ip = jnp.sum(g * q, axis=-1)[None, :]           # [1, _CB]
+            o_ref[j, pl.ds(t, 1), :] = ip
+        return carry
+
+    start(0, 0, 0)
+    jax.lax.fori_loop(0, tq, row, 0)
+
+
+def _gather_ip(points, queries, nbr_ids, *, hbm: bool, tq: int,
+               interpret: bool) -> jax.Array:
+    """``ip[q, c] = <queries[q], points[max(nbr_ids[q, c], 0)]>`` [Q, C]:
+    f32 for float points (f32/bf16), exact int32 for int8 points with
+    int32 (quantized) queries.  ``hbm`` leaves the points in HBM
+    (``MemorySpace.ANY``); otherwise the whole block is VMEM-resident."""
+    nq, c = nbr_ids.shape
+    pack = 4 // points.dtype.itemsize
+    if pack > 1:
+        points = _pad(points, 0, _PACKED_TILE_ROWS, 0)   # whole DMA tiles
+    points = _pad(points, 1, LANE, 0)
+    queries = _pad(_pad(queries, 0, tq, 0), 1, LANE, 0)
+    nbr_ids = _pad(_pad(nbr_ids, 0, tq, -1), 1, _CB, -1)
+    qp, dp = queries.shape
+    cp = nbr_ids.shape[1]
+    nb = cp // _CB
+    wrows = 1 if pack == 1 else _PACKED_TILE_ROWS // pack
+    acc = jnp.int32 if points.dtype == jnp.int8 else jnp.float32
+    pts_spec = (pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY) if hbm
+                else pl.BlockSpec(points.shape, lambda r: (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_gather_ip_kernel, pack=pack),
+        out_shape=jax.ShapeDtypeStruct((nb, qp, _CB), acc),
+        grid=(qp // tq,),
+        in_specs=[
+            pl.BlockSpec((tq, cp), lambda r: (r, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((tq, dp), lambda r: (r, 0)),
+            pts_spec,
+        ],
+        out_specs=pl.BlockSpec((nb, tq, _CB), lambda r: (0, r, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, _CB * wrows, dp),
+                       points.dtype if pack == 1 else jnp.int32),
+            pltpu.VMEM((2, _CB, LANE), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        interpret=interpret,
+    )(nbr_ids, queries, points)
+    return jnp.swapaxes(out, 0, 1).reshape(qp, cp)[:nq, :c]
+
+
+def _f32_distance(ip, norms, queries, nbr_ids, metric):
+    """The norm expansion around an f32 inner-product block, in the op
+    order of ``ref.gather_distance_hbm_ref`` (lane-padded query norms)."""
+    q32 = _pad(queries.astype(jnp.float32), 1, LANE, 0)
+    n2 = norms.astype(jnp.float32)[jnp.maximum(nbr_ids, 0)]
+    if metric == "mips":
+        d = -ip
+    elif metric == "cosine":
+        qn = jnp.sqrt(jnp.sum(q32 * q32, axis=-1))
+        d = 1.0 - ip / jnp.maximum(qn[:, None] * n2, 1e-30)
+    else:
+        q2 = jnp.sum(q32 * q32, axis=-1)
+        d = jnp.maximum(q2[:, None] + n2 - 2.0 * ip, 0.0)
+    return jnp.where(nbr_ids >= 0, d, jnp.inf)
+
+
+def _int8_distance(points, scales, norms, queries, q_norms, nbr_ids, *,
+                   metric, hbm, tq, interpret):
+    """Quantized distance block: the query is quantized with the packing's
+    own scheme, the kernel returns the exact int32 inner products, and
+    the rescale + exact-norm expansion follow in the op order of
+    ``ref.gather_distance_int8_core``."""
+    if points.dtype != jnp.int8:
+        raise TypeError("the int8 gather-distance kernels expect int8 points")
+    q8, sq = _ref.quantize_symmetric(queries.astype(jnp.float32))
+    ip = _gather_ip(points, q8.astype(jnp.int32), nbr_ids, hbm=hbm, tq=tq,
+                    interpret=interpret)
+    safe = jnp.maximum(nbr_ids, 0)
+    sg = scales.astype(jnp.float32)[safe]
+    n2 = norms.astype(jnp.float32)[safe]
+    qa = q_norms.astype(jnp.float32)
+    ipf = ip.astype(jnp.float32) * (sq[:, None] * sg)
+    if metric == "mips":
+        d = -ipf
+    elif metric == "cosine":
+        d = 1.0 - ipf / jnp.maximum(qa[:, None] * n2, 1e-30)
+    else:
+        d = jnp.maximum(qa[:, None] + n2 - 2.0 * ipf, 0.0)
+    return jnp.where(nbr_ids >= 0, d, jnp.inf)
+
+
+def _empty(nbr_ids):
+    return jnp.full(nbr_ids.shape, jnp.inf, jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Public entries: {f32, int8} x {VMEM-resident, HBM-streaming}
+# ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("metric", "tq", "interpret"))
 def gather_distance(
@@ -161,210 +328,16 @@ def gather_distance(
     tq: int = _TQ,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused gather-distance block [Q, C] f32; +inf where ``nbr_ids < 0``.
-
-    Semantics identical to ``kernels.ref.gather_distance_ref`` (tested in
-    interpret mode on CPU).
-    """
-    nq, c = nbr_ids.shape
-    if nq == 0 or c == 0:
-        return jnp.full((nq, c), jnp.inf, jnp.float32)
-    points = _pad(_pad(points, 0, 8, 0), 1, LANE, 0)
-    norms = _pad(norms.astype(jnp.float32), 0, 8, 0.0).reshape(1, -1)
-    queries = _pad(_pad(queries, 0, tq, 0), 1, LANE, 0)
-    nbr_ids = _pad(_pad(nbr_ids, 0, tq, -1), 1, LANE, -1)
-    qp, dp = queries.shape
-    cp = nbr_ids.shape[1]
-    np_ = points.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_gather_distance_kernel, metric=metric),
-        out_shape=jax.ShapeDtypeStruct((qp, cp), jnp.float32),
-        grid=(qp // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((np_, dp), lambda r: (0, 0)),
-            pl.BlockSpec((1, norms.shape[1]), lambda r: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-        interpret=interpret,
-    )(queries, nbr_ids, points, norms)
-    return out[:nq, :c]
-
-
-def _gather_distance_int8_kernel(q_ref, ids_ref, pts_ref, scl_ref, n2_ref,
-                                 qa_ref, o_ref, *, metric: str):
-    q = q_ref[...].astype(jnp.float32)          # [TQ, d]
-    ids = ids_ref[...]                          # [TQ, C]
-    tq, c = ids.shape
-    flat = jnp.maximum(ids.reshape(-1), 0)      # [TQ*C]
-    g = jnp.take(pts_ref[...], flat, axis=0)    # [TQ*C, d] int8 gather
-    sg = jnp.take(scl_ref[...].reshape(-1), flat).reshape(tq, c)
-    # query quantized with the SAME symmetric scheme as the packed points
-    # (max reduction is padding-safe, round/clip elementwise => the oracle
-    # quantizes bit-identically on the unpadded array)
-    q8, sq = _ref.quantize_symmetric(q)
-    # int8 x int8 -> int32 batched matvec on the MXU: the accumulation is
-    # EXACT; only the single rescale below carries quantization error
-    ip = jax.lax.dot_general(
-        q8, g.reshape(tq, c, -1), (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32,
-    )                                           # [TQ, C] int32
-    ipf = ip.astype(jnp.float32) * (sq[:, None] * sg)
-    rows = pl.program_id(0) * tq + \
-        jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)[:, 0]
-    qa = jnp.take(qa_ref[...].reshape(-1), rows)          # [TQ]
-    if metric == "mips":
-        d = -ipf
-    elif metric == "cosine":
-        n2 = jnp.take(n2_ref[...].reshape(-1), flat).reshape(tq, c)
-        d = 1.0 - ipf / jnp.maximum(qa[:, None] * n2, 1e-30)
-    else:
-        n2 = jnp.take(n2_ref[...].reshape(-1), flat).reshape(tq, c)
-        d = jnp.maximum(qa[:, None] + n2 - 2.0 * ipf, 0.0)
-    o_ref[...] = jnp.where(ids >= 0, d, jnp.inf)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "tq", "interpret"))
-def gather_distance_int8(
-    points: jax.Array,   # [n, d] int8 (quantize_symmetric packing)
-    scales: jax.Array,   # [n] f32 per-point dequantization scales
-    norms: jax.Array,    # [n] f32 EXACT norms (computed pre-quantization)
-    queries: jax.Array,  # [Q, d] f32
-    q_norms: jax.Array,  # [Q] f32 query norm terms (metrics.point_norms)
-    nbr_ids: jax.Array,  # [Q, C] int32, -1 = padding
-    *,
-    metric: str = "l2",
-    tq: int = _TQ,
-    interpret: bool = False,
-) -> jax.Array:
-    """Quantized fused gather-distance block [Q, C] f32 (+inf at pads).
-
-    The int8 serving twin of ``gather_distance``: the points block lives
-    VMEM-resident at 1/4 the f32 footprint (``fits_vmem`` sees the
-    itemsize, so shards 4x larger auto-enable the kernel), the gathered
-    rows hit the MXU as an int8 x int8 -> int32 batched matvec, and the
-    per-point scale + norm expansion are fused into the same pass.  The
-    query side is quantized per-row IN the kernel (symmetric, the
-    packing's own scheme — reused each grid step from the f32 query
-    tile); the query norm terms arrive precomputed (``q_norms``, from
-    ``metrics.point_norms`` on the queries, once per batch) so both
-    norm halves of
-    the distance expansion stay full-precision.  Semantics identical to
-    ``kernels.ref.gather_distance_int8_ref`` — bit-for-bit in interpret
-    mode (integer ops exact, f32 ops in matching order).
-    """
-    if points.dtype != jnp.int8:
-        raise TypeError("gather_distance_int8 expects int8 points")
-    nq, c = nbr_ids.shape
-    if nq == 0 or c == 0:
-        return jnp.full((nq, c), jnp.inf, jnp.float32)
-    q32 = queries.astype(jnp.float32)
-    qa = q_norms.astype(jnp.float32)
-    points = _pad(_pad(points, 0, _SUBLANE_I8, 0), 1, LANE, 0)
-    scales = _pad(scales.astype(jnp.float32), 0, _SUBLANE_I8, 0.0)
-    norms = _pad(norms.astype(jnp.float32), 0, _SUBLANE_I8, 0.0)
-    queries = _pad(_pad(q32, 0, tq, 0), 1, LANE, 0)
-    nbr_ids = _pad(_pad(nbr_ids, 0, tq, -1), 1, LANE, -1)
-    qa = _pad(qa, 0, tq, 0.0).reshape(1, -1)
-    qp, dp = queries.shape
-    cp = nbr_ids.shape[1]
-    np_ = points.shape[0]
-    scales = scales.reshape(1, np_)
-    norms = norms.reshape(1, np_)
-    out = pl.pallas_call(
-        functools.partial(_gather_distance_int8_kernel, metric=metric),
-        out_shape=jax.ShapeDtypeStruct((qp, cp), jnp.float32),
-        grid=(qp // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((np_, dp), lambda r: (0, 0)),
-            pl.BlockSpec((1, np_), lambda r: (0, 0)),
-            pl.BlockSpec((1, np_), lambda r: (0, 0)),
-            pl.BlockSpec((1, qp), lambda r: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-        interpret=interpret,
-    )(queries, nbr_ids, points, scales, norms, qa)
-    return out[:nq, :c]
-
-
-# ---------------------------------------------------------------------------
-# HBM-streaming kernels: points stay in HBM, neighbor rows are DMA'd
-# ---------------------------------------------------------------------------
-
-def _row_copies(pts_hbm, ids_ref, scratch, sem, slot, t, cp):
-    """The ``cp`` single-row HBM->VMEM async copies for query row ``t``
-    into scratch buffer ``slot``.  ``.start()`` and ``.wait()`` must see
-    the SAME copy descriptors, so both phases rebuild them through here;
-    -1 ids fetch row 0 (their output is masked to +inf afterwards)."""
-    def one(c, _):
-        sid = jnp.maximum(ids_ref[t, c], 0)
-        copy = pltpu.make_async_copy(
-            pts_hbm.at[pl.ds(sid, 1), :],
-            scratch.at[slot, pl.ds(c, 1), :],
-            sem.at[slot],
-        )
-        return copy
-
-    return one
-
-
-def _stream_rows(pts_hbm, ids_ref, scratch, sem, tq, cp, compute_row):
-    """Double-buffered row loop shared by both HBM kernels: issue row 0's
-    copies, then per row prefetch row ``t+1`` into the other buffer,
-    drain row ``t``, and hand its gathered block to ``compute_row``."""
-    def issue(slot, t):
-        def one(c, carry):
-            _row_copies(pts_hbm, ids_ref, scratch, sem, slot, t, cp)(
-                c, None).start()
-            return carry
-        jax.lax.fori_loop(0, cp, one, 0)
-
-    def drain(slot, t):
-        def one(c, carry):
-            _row_copies(pts_hbm, ids_ref, scratch, sem, slot, t, cp)(
-                c, None).wait()
-            return carry
-        jax.lax.fori_loop(0, cp, one, 0)
-
-    issue(0, 0)
-
-    def body(t, carry):
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < tq)
-        def _prefetch_next():
-            issue(jax.lax.rem(t + 1, 2), t + 1)
-
-        drain(slot, t)
-        compute_row(t, scratch[slot])
-        return carry
-
-    jax.lax.fori_loop(0, tq, body, 0)
-
-
-def _gather_distance_hbm_kernel(q_ref, ids_ref, n2g_ref, pts_hbm, o_ref,
-                                scratch, sem, *, metric: str):
-    tq, cp = ids_ref.shape
-
-    def compute_row(t, g):                       # g: [Cp, dp] gathered rows
-        q = q_ref[t, :].astype(jnp.float32)      # [dp]
-        ids = ids_ref[t, :]
-        ip = jnp.sum(g.astype(jnp.float32) * q[None, :], axis=-1)   # [Cp]
-        n2 = n2g_ref[t, :]                       # pre-gathered norms
-        if metric == "mips":
-            d = -ip
-        elif metric == "cosine":
-            qn = jnp.sqrt(jnp.sum(q * q))
-            d = 1.0 - ip / jnp.maximum(qn * n2, 1e-30)
-        else:
-            q2 = jnp.sum(q * q)
-            d = jnp.maximum(q2 + n2 - 2.0 * ip, 0.0)
-        o_ref[pl.ds(t, 1), :] = jnp.where(ids >= 0, d, jnp.inf)[None]
-
-    _stream_rows(pts_hbm, ids_ref, scratch, sem, tq, cp, compute_row)
+    """Fused gather-distance block [Q, C] f32; +inf where ``nbr_ids < 0``,
+    with the points block VMEM-resident.  Semantics of
+    ``kernels.ref.gather_distance_ref``; bits identical to
+    ``gather_distance_hbm`` (the same kernel body, only the points'
+    memory space differs)."""
+    if 0 in nbr_ids.shape:
+        return _empty(nbr_ids)
+    ip = _gather_ip(points, queries.astype(jnp.float32), nbr_ids, hbm=False,
+                    tq=tq, interpret=interpret)
+    return _f32_distance(ip, norms, queries, nbr_ids, metric)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tq", "interpret"))
@@ -380,75 +353,42 @@ def gather_distance_hbm(
 ) -> jax.Array:
     """HBM-streaming gather-distance block [Q, C] f32; +inf at ``-1`` ids.
 
-    The over-VMEM-budget twin of ``gather_distance``: the points block is
-    placed in ``TPUMemorySpace.ANY`` (HBM) and never copied wholesale;
-    per query row the C neighbor rows arrive in a double-buffered VMEM
-    scratch via per-row ``make_async_copy`` DMAs, overlapped with the
-    previous row's distance compute.  The point-side norms are gathered
-    OUTSIDE the kernel into a [Q, C] block (a gather has no arithmetic,
-    so it cannot move bits) and ride in as a regular VMEM input.
+    The over-VMEM-budget twin of ``gather_distance``: the points stay in
+    ``MemorySpace.ANY`` (HBM) and only the candidates' rows move, by
+    double-buffered per-row DMAs.  Bit-identical in interpret mode to
+    ``kernels.ref.gather_distance_hbm_ref``, which reduces the same
+    lane-padded extent in the same order."""
+    if 0 in nbr_ids.shape:
+        return _empty(nbr_ids)
+    ip = _gather_ip(points, queries.astype(jnp.float32), nbr_ids, hbm=True,
+                    tq=tq, interpret=interpret)
+    return _f32_distance(ip, norms, queries, nbr_ids, metric)
 
+
+@functools.partial(jax.jit, static_argnames=("metric", "tq", "interpret"))
+def gather_distance_int8(
+    points: jax.Array,   # [n, d] int8 (quantize_symmetric packing)
+    scales: jax.Array,   # [n] f32 per-point dequantization scales
+    norms: jax.Array,    # [n] f32 EXACT norms (computed pre-quantization)
+    queries: jax.Array,  # [Q, d] f32
+    q_norms: jax.Array,  # [Q] f32 query norm terms (metrics.point_norms)
+    nbr_ids: jax.Array,  # [Q, C] int32, -1 = padding
+    *,
+    metric: str = "l2",
+    tq: int = _TQ,
+    interpret: bool = False,
+) -> jax.Array:
+    """Quantized fused gather-distance block [Q, C] f32 (+inf at pads),
+    int8 points VMEM-resident at 1/4 the f32 footprint.  The query is
+    quantized with the packing's own symmetric scheme, the inner product
+    is exact in int32, and both norm halves stay full-precision.
     Bit-identical in interpret mode to ``kernels.ref.
-    gather_distance_hbm_ref`` — the oracle mirrors the kernel's reduction
-    shape (d padded to the lane width, elementwise-multiply + sum) so the
-    f32 accumulation order matches exactly.
-    """
-    nq, c = nbr_ids.shape
-    if nq == 0 or c == 0:
-        return jnp.full((nq, c), jnp.inf, jnp.float32)
-    # pre-gather the per-candidate norms (bit-free) before any padding
-    n2g = norms.astype(jnp.float32)[jnp.maximum(nbr_ids, 0)]       # [Q, C]
-    points = _pad(points, 1, LANE, 0)
-    queries = _pad(_pad(queries, 0, tq, 0), 1, LANE, 0)
-    nbr_ids = _pad(_pad(nbr_ids, 0, tq, -1), 1, LANE, -1)
-    n2g = _pad(_pad(n2g, 0, tq, 0.0), 1, LANE, 0.0)
-    qp, dp = queries.shape
-    cp = nbr_ids.shape[1]
-    out = pl.pallas_call(
-        functools.partial(_gather_distance_hbm_kernel, metric=metric),
-        out_shape=jax.ShapeDtypeStruct((qp, cp), jnp.float32),
-        grid=(qp // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        ],
-        out_specs=pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, cp, dp), points.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(queries, nbr_ids, n2g, points)
-    return out[:nq, :c]
-
-
-def _gather_distance_int8_hbm_kernel(q_ref, ids_ref, sg_ref, n2g_ref, qa_ref,
-                                     pts_hbm, o_ref, scratch, sem, *,
-                                     metric: str):
-    tq, cp = ids_ref.shape
-    # quantize the query tile once per grid step — row-local and
-    # order-independent, so the bits match the oracle's per-batch pass
-    q8, sq = _ref.quantize_symmetric(q_ref[...].astype(jnp.float32))
-
-    def compute_row(t, g):                       # g: [Cp, dp] int8 rows
-        ids = ids_ref[t, :]
-        # int8 x int8 -> int32 accumulation is EXACT (order-free), so the
-        # streamed per-row reduction cannot differ from the oracle einsum
-        ip = jnp.sum(g.astype(jnp.int32) * q8[t, :].astype(jnp.int32)[None, :],
-                     axis=-1)                    # [Cp] int32
-        ipf = ip.astype(jnp.float32) * (sq[t] * sg_ref[t, :])
-        qa = qa_ref[t, 0]
-        if metric == "mips":
-            d = -ipf
-        elif metric == "cosine":
-            d = 1.0 - ipf / jnp.maximum(qa * n2g_ref[t, :], 1e-30)
-        else:
-            d = jnp.maximum(qa + n2g_ref[t, :] - 2.0 * ipf, 0.0)
-        o_ref[pl.ds(t, 1), :] = jnp.where(ids >= 0, d, jnp.inf)[None]
-
-    _stream_rows(pts_hbm, ids_ref, scratch, sem, tq, cp, compute_row)
+    gather_distance_int8_ref``."""
+    if 0 in nbr_ids.shape:
+        return _empty(nbr_ids)
+    return _int8_distance(points, scales, norms, queries, q_norms, nbr_ids,
+                          metric=metric, hbm=False, tq=tq,
+                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tq", "interpret"))
@@ -464,55 +404,13 @@ def gather_distance_int8_hbm(
     tq: int = _TQ,
     interpret: bool = False,
 ) -> jax.Array:
-    """HBM-streaming quantized gather-distance block [Q, C] f32.
-
-    The int8-first streaming kernel (the DMA traffic is 1/4 of the f32
-    twin's per row): int8 points stay in HBM, neighbor rows stream into a
-    double-buffered int8 VMEM scratch, and the per-point scales + exact
-    norms are pre-gathered outside the kernel into [Q, C] f32 blocks.
-    Query quantization happens in-kernel per tile exactly as in
-    ``gather_distance_int8``.
-
-    Bit-identical in interpret mode to ``kernels.ref.
-    gather_distance_int8_ref`` — the SAME oracle as the VMEM-resident
-    int8 kernel, because the int32 inner-product accumulation is
-    order-free and every f32 op is elementwise in matching order, so the
-    streaming row-at-a-time schedule cannot move bits.
-    """
-    if points.dtype != jnp.int8:
-        raise TypeError("gather_distance_int8_hbm expects int8 points")
-    nq, c = nbr_ids.shape
-    if nq == 0 or c == 0:
-        return jnp.full((nq, c), jnp.inf, jnp.float32)
-    safe = jnp.maximum(nbr_ids, 0)
-    sg = scales.astype(jnp.float32)[safe]                          # [Q, C]
-    n2g = norms.astype(jnp.float32)[safe]                          # [Q, C]
-    points = _pad(points, 1, LANE, 0)
-    queries = _pad(_pad(queries.astype(jnp.float32), 0, tq, 0), 1, LANE, 0)
-    nbr_ids = _pad(_pad(nbr_ids, 0, tq, -1), 1, LANE, -1)
-    sg = _pad(_pad(sg, 0, tq, 0.0), 1, LANE, 0.0)
-    n2g = _pad(_pad(n2g, 0, tq, 0.0), 1, LANE, 0.0)
-    qa = _pad(q_norms.astype(jnp.float32), 0, tq, 0.0)[:, None]    # [Qp, 1]
-    qa = _pad(qa, 1, LANE, 0.0)
-    qp, dp = queries.shape
-    cp = nbr_ids.shape[1]
-    out = pl.pallas_call(
-        functools.partial(_gather_distance_int8_hbm_kernel, metric=metric),
-        out_shape=jax.ShapeDtypeStruct((qp, cp), jnp.float32),
-        grid=(qp // tq,),
-        in_specs=[
-            pl.BlockSpec((tq, dp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-            pl.BlockSpec((tq, qa.shape[1]), lambda r: (r, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        ],
-        out_specs=pl.BlockSpec((tq, cp), lambda r: (r, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, cp, dp), jnp.int8),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(queries, nbr_ids, sg, n2g, qa, points)
-    return out[:nq, :c]
+    """HBM-streaming quantized gather-distance block [Q, C] f32: int8
+    points stay in HBM and each candidate's 8-row int8 tile is DMA'd
+    (``_gather_ip_kernel``).  Shares ``kernels.ref.
+    gather_distance_int8_ref`` with the VMEM-resident twin, bit-for-bit
+    in interpret mode."""
+    if 0 in nbr_ids.shape:
+        return _empty(nbr_ids)
+    return _int8_distance(points, scales, norms, queries, q_norms, nbr_ids,
+                          metric=metric, hbm=True, tq=tq,
+                          interpret=interpret)

@@ -63,6 +63,10 @@ def leaf_topk_ref(
     # stable top-k with index tie-breaking: sort (dist, idx)
     iota = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), d.shape)
     sd, si = jax.lax.sort((d, iota), dimension=-1, num_keys=2)
+    if k > c:   # fewer co-leaf points than k: the tail stays padding
+        pad = [(0, 0)] * (sd.ndim - 1) + [(0, k - c)]
+        sd = jnp.pad(sd, pad, constant_values=jnp.inf)
+        si = jnp.pad(si, pad, constant_values=-1)
     sd, si = sd[..., :k], si[..., :k]
     ok = jnp.isfinite(sd)
     return jnp.where(ok, si, -1), jnp.where(ok, sd, jnp.inf)

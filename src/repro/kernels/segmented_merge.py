@@ -16,10 +16,12 @@ produces R(A ∪ B) per row without any sort:
     through one-hot selects instead of a sort network;
   * truncate to l_max, pad with (id -1, hash 0, dist +inf).
 
-Everything is elementwise compares + small-axis reductions on [rows, l, l]
-tiles — pure VPU work, no MXU, no gather/scatter.  Bit-identical to the
-``hashprune_batch``-based fallback in ``merge_segmented_edges`` (asserted
-by tests in interpret mode).
+Everything is elementwise compares + reductions on one row's [l, l]
+tiles — pure VPU/XLU work, no MXU, no gather/scatter, no cumsum (Mosaic
+has none: the prefix counts are one more [l, l] compare).  Bit-identical
+to the ``hashprune_batch``-based fallback in ``merge_segmented_edges``
+(asserted by tests in interpret mode) and to
+``kernels.ref.merge_sorted_reservoirs_ref``.
 """
 from __future__ import annotations
 
@@ -39,46 +41,90 @@ def _lt(d1, i1, d2, i2):
     return (d1 < d2) | ((d1 == d2) & (i1 < i2))
 
 
-def _select(onehot, x, fill):
-    """Per output slot, pick the single input slot flagged in ``onehot``
-    [R, l_out, l_in]; ``fill`` where no slot is flagged (avoids 0 * inf)."""
-    picked = jnp.sum(jnp.where(onehot, x[:, None, :], 0), axis=2)
-    return jnp.where(jnp.any(onehot, axis=2), picked, fill)
-
-
 def _merge_rows_kernel(a_i_ref, a_h_ref, a_d_ref,
                        b_i_ref, b_h_ref, b_d_ref,
                        o_i_ref, o_h_ref, o_d_ref, *, l: int):
+    """One block of ``_ROWS`` rows, one row at a time on [l, l] tiles.
+
+    Each pairwise quantity needs one side along sublanes (a column) and
+    the other along lanes (a row); the columns come from in-register
+    transposes of the [_ROWS, l] blocks.  The per-row results leave in
+    column form and are transposed back once per block."""
+    rows = a_i_ref.shape[0]
     ai, ah, ad = a_i_ref[...], a_h_ref[...], a_d_ref[...]   # [R, l]
     bi, bh, bd = b_i_ref[...], b_h_ref[...], b_d_ref[...]
-    va, vb = ai != -1, bi != -1
+    ai_t, ah_t, ad_t, bi_t, bh_t, bd_t = (
+        x.T for x in (ai, ah, ad, bi, bh, bd))               # [l, R]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    before = sub < lane                      # [i', i]: i' strictly before i
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (rows, l), 0)
+    col_id = jax.lax.broadcasted_iota(jnp.int32, (l, rows), 1)
 
-    # pair [r, i, j] = (A slot i, B slot j)
-    b_lt_a = _lt(bd[:, None, :], bi[:, None, :], ad[:, :, None], ai[:, :, None])
-    a_le_b = ~b_lt_a
-    pair_ok = va[:, :, None] & vb[:, None, :]
-    collide = (ah[:, :, None] == bh[:, None, :]) & pair_ok
+    def row(x, r):
+        return x[r:r + 1, :]                 # [1, l]
 
-    # bucket dedup: the strictly-smaller key wins; exact key ties keep A
-    keep_a = va & ~jnp.any(collide & b_lt_a, axis=2)
-    keep_b = vb & ~jnp.any(collide & a_le_b, axis=1)
+    def col(x_t, r):
+        return x_t[:, r:r + 1]               # [l, 1]
+
+    def b_lt_a(r):
+        """[j, i]: B slot j's key is below A slot i's (B on sublanes)."""
+        return _lt(col(bd_t, r), col(bi_t, r), row(ad, r), row(ai, r))
+
+    def a_le_b(r):
+        """[i, j]: A slot i's key is not above B slot j's (A on
+        sublanes) — A wins exact (dist, id) ties."""
+        return ~_lt(row(bd, r), row(bi, r), col(ad_t, r), col(ai_t, r))
+
+    # bucket dedup: a cross-side hash collision drops the larger key
+    keep_a = jnp.zeros((rows, l), jnp.int32)
+    keep_b = jnp.zeros((rows, l), jnp.int32)
+    for r in range(rows):
+        va, vb = row(ai, r) != -1, row(bi, r) != -1
+        va_t, vb_t = col(ai_t, r) != -1, col(bi_t, r) != -1
+        hit_b = (col(bh_t, r) == row(ah, r)) & vb_t & va & b_lt_a(r)
+        ka = va & ~jnp.any(hit_b, axis=0, keepdims=True)
+        hit_a = (col(ah_t, r) == row(bh, r)) & va_t & vb & a_le_b(r)
+        kb = vb & ~jnp.any(hit_a, axis=0, keepdims=True)
+        keep_a = jnp.where(row_id == r, ka.astype(jnp.int32), keep_a)
+        keep_b = jnp.where(row_id == r, kb.astype(jnp.int32), keep_b)
+    keep_a_t, keep_b_t = keep_a.T, keep_b.T
 
     # survivor rank = own-side survivors before me + other-side survivors
-    # with a smaller key (A wins (dist, id) ties, so B counts a_le_b)
-    excl = lambda k: jnp.cumsum(k.astype(jnp.int32), axis=1) - k.astype(jnp.int32)
-    pos_a = excl(keep_a) + jnp.sum(
-        (keep_b[:, None, :] & b_lt_a).astype(jnp.int32), axis=2)
-    pos_b = excl(keep_b) + jnp.sum(
-        (keep_a[:, :, None] & a_le_b).astype(jnp.int32), axis=1)
+    # with a smaller key; each survivor lands in output slot = its rank
+    out_i = jnp.zeros((l, rows), jnp.int32)
+    out_h = jnp.zeros((l, rows), jnp.int32)
+    out_d = jnp.zeros((l, rows), jnp.float32)
+    for r in range(rows):
+        ka_t, kb_t = col(keep_a_t, r) == 1, col(keep_b_t, r) == 1
+        ka, kb = row(keep_a, r) == 1, row(keep_b, r) == 1
+        pos_a = (jnp.sum((ka_t & before).astype(jnp.int32), axis=0,
+                         keepdims=True)
+                 + jnp.sum((kb_t & b_lt_a(r)).astype(jnp.int32), axis=0,
+                           keepdims=True))                   # [1, l]
+        pos_b = (jnp.sum((kb_t & before).astype(jnp.int32), axis=0,
+                         keepdims=True)
+                 + jnp.sum((ka_t & a_le_b(r)).astype(jnp.int32), axis=0,
+                           keepdims=True))
+        oh_a = ka & (pos_a == sub)           # [slot, i]
+        oh_b = kb & (pos_b == sub)           # [slot, j]
 
-    slot = jax.lax.broadcasted_iota(jnp.int32, (ai.shape[0], l, l), 1)
-    oh_a = keep_a[:, None, :] & (pos_a[:, None, :] == slot)
-    oh_b = keep_b[:, None, :] & (pos_b[:, None, :] == slot)
-    o_i_ref[...] = _select(oh_a, ai, 0) + _select(oh_b, bi, 0) - jnp.where(
-        jnp.any(oh_a | oh_b, axis=2), 0, 1)
-    o_h_ref[...] = _select(oh_a, ah, 0) + _select(oh_b, bh, 0)
-    o_d_ref[...] = jnp.minimum(_select(oh_a, ad, jnp.inf),
-                               _select(oh_b, bd, jnp.inf))
+        def pick(oh, x, fill):
+            v = jnp.sum(jnp.where(oh, x, 0), axis=1, keepdims=True)
+            return jnp.where(jnp.any(oh, axis=1, keepdims=True), v, fill)
+
+        taken = jnp.any(oh_a | oh_b, axis=1, keepdims=True)
+        oi = (pick(oh_a, row(ai, r), 0) + pick(oh_b, row(bi, r), 0)
+              - jnp.where(taken, 0, 1))
+        oh = pick(oh_a, row(ah, r), 0) + pick(oh_b, row(bh, r), 0)
+        od = jnp.minimum(pick(oh_a, row(ad, r), jnp.inf),
+                         pick(oh_b, row(bd, r), jnp.inf))
+        out_i = jnp.where(col_id == r, oi, out_i)
+        out_h = jnp.where(col_id == r, oh, out_h)
+        out_d = jnp.where(col_id == r, od, out_d)
+    o_i_ref[...] = out_i.T
+    o_h_ref[...] = out_h.T
+    o_d_ref[...] = out_d.T
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

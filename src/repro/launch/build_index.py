@@ -45,12 +45,10 @@ from repro.core import sketch as _sketch
 from repro.core.hashprune import (INVALID_ID, Reservoir, merge_flat_edges,
                                   merge_segmented_edges, reservoir_init)
 from repro.core.leader_assign import leader_assign
-from repro.distributed import compat as _compat
 from repro.core.robust_prune import prune_reservoir_block
 from repro.distributed.routing import group_by_capacity
 
 INF = jnp.float32(jnp.inf)
-_shard_map = _compat.shard_map_norep
 
 
 # ---------------------------------------------------------------------------
@@ -351,10 +349,10 @@ def make_tile_step(mesh: Mesh, p: DistBuildParams):
 
     sharded = P(axes)
     rep = P()
-    step = _shard_map(
+    step = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(sharded, rep, sharded, sharded, sharded),
-        out_specs=(sharded, sharded, sharded, rep),
+        out_specs=(sharded, sharded, sharded, rep), check_vma=False,
     )
 
     def tile_step(points, hyperplanes, res: Reservoir):
@@ -423,10 +421,10 @@ def make_final_prune_step(mesh: Mesh, p: DistBuildParams):
                 gd.reshape(n_loc, p.max_deg))
 
     sharded = P(axes)
-    return _shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(sharded, sharded, sharded),
-        out_specs=(sharded, sharded),
+        out_specs=(sharded, sharded), check_vma=False,
     )
 
 

@@ -29,7 +29,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map_norep as _shard_map
 from repro.models.layers import Params, dense_init
 
 
@@ -224,9 +223,9 @@ def moe_apply_ep(
         y = jnp.einsum("nkd,nk->nd", y_flat.reshape(n, top_k, d), gate_w)
         return y.reshape(bl, tl, d).astype(xl.dtype), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, PS(), w_spec, w_spec, w_spec),
-        out_specs=(x_spec, PS()),
+        out_specs=(x_spec, PS()), check_vma=False,
     )(x, p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

@@ -289,7 +289,7 @@ def test_jaxpr_f64_fires_only_under_x64():
 
     args = (jax.ShapeDtypeStruct((4,), jnp.float32),)
     assert jaxpr_audit.trace_and_audit(f, args, "fx.py", "f") == []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def g(x):
             return x.astype(jnp.float64) * 2.0
         fs = jaxpr_audit.trace_and_audit(g, args, "fx.py", "g")

@@ -10,10 +10,7 @@ from repro.distributed import sharding as shd
 
 
 def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)          # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # jax 0.4.x
+    return AbstractMesh(sizes, names)
 
 
 @pytest.fixture(scope="module")

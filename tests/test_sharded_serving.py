@@ -482,3 +482,47 @@ def test_sharded_converged_telemetry(built):
     _, stats1 = ssv.search(x[:5], k=5, beam=16, iters=1, with_stats=True)
     assert not stats1["converged"].any()
     assert stats["healthy_shards"] == 1
+
+
+def test_sharded_packing_reaches_every_shard_row():
+    """A shard's graph is searched from the shard's own entry point, so
+    the packing links every component it holds to that entry — even
+    when the global graph is split (two separate 5-cycles here)."""
+    from repro.core.pipnn import _reachable
+
+    g = np.full((10, 4), -1, np.int32)
+    for i in range(5):
+        g[i, 0] = (i + 1) % 5
+        g[5 + i, 0] = 5 + (i + 1) % 5
+    x = np.arange(10, dtype=np.float32)[:, None] * np.ones((1, 3),
+                                                           np.float32)
+    sv = ShardedServingIndex.from_graph(g, x, 0, mesh=_mesh(1))
+    local = np.asarray(sv.graph)[0]
+    start = int(np.asarray(sv.starts)[0])
+    assert _reachable(local, [start]).all()
+    found = sv.search(x, k=1, beam=4)
+    np.testing.assert_array_equal(found[:, 0], np.arange(10))
+
+
+@multidevice
+def test_sharded_recall_parity_on_separated_clusters():
+    """Clusters farther apart than any leaf: every shard must still reach
+    all of its own clusters from its entry point."""
+    from repro.core.leaf import LeafParams
+    from repro.core.pipnn import PiPNNParams
+    from repro.core.rbc import RBCParams
+    from repro.data.pipeline import (VectorPipelineConfig, make_queries,
+                                     make_vectors)
+
+    cfg = VectorPipelineConfig(n=3000, dim=16, n_clusters=8,
+                               cluster_scale=20.0, seed=5)
+    x, q = make_vectors(cfg), make_queries(cfg, 100)
+    idx = pipnn.build(x, PiPNNParams(
+        rbc=RBCParams(c_max=128, c_min=16, p_samp=0.05, fanout=(2, 1)),
+        leaf=LeafParams(k=2), max_deg=16, seed=0))
+    truth = brute_force_knn(x, q, 10)
+    single = recall_at_k(ServingIndex.from_index(idx, x).search(
+        q, k=10, beam=64), truth, 10)
+    sharded = recall_at_k(ServingIndex.from_index(idx, x, mesh=_mesh(4))
+                          .search(q, k=10, beam=64), truth, 10)
+    assert single > 0.9 and abs(sharded - single) <= 0.01

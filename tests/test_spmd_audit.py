@@ -17,7 +17,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import spmd_audit as sa
 from repro.analysis.spmd_audit import SPMDProgram, SPMDSpec
-from repro.distributed.compat import shard_map_norep
 
 NDEV = len(jax.devices())
 
@@ -46,8 +45,8 @@ def _psum_program(s):
     def body(x):
         return jax.lax.psum(x, "ax")
 
-    fn = jax.jit(shard_map_norep(body, mesh=mesh, in_specs=(P("ax"),),
-                                 out_specs=P("ax")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("ax"),),
+                               out_specs=P("ax"), check_vma=False))
     return SPMDProgram(fn=fn, args=(jax.ShapeDtypeStruct((s, 4), jnp.float32),),
                        arg_names=("x",), sharded=frozenset({"x"}))
 
@@ -80,8 +79,8 @@ def test_collectives_in_sees_through_nesting():
         c, _ = jax.lax.scan(step, x, None, length=2)
         return c
 
-    fn = jax.jit(shard_map_norep(body, mesh=mesh, in_specs=(P("ax"),),
-                                 out_specs=P("ax")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("ax"),),
+                               out_specs=P("ax"), check_vma=False))
     got = sa.collectives_in(fn, (jax.ShapeDtypeStruct((1, 4), jnp.float32),))
     assert ("psum", "ax") in got
 
@@ -96,9 +95,9 @@ def _mislabeled_program(s):
     def body(x, y):
         return x + y.sum()
 
-    fn = jax.jit(shard_map_norep(body, mesh=mesh,
-                                 in_specs=(P("ax"), P()),
-                                 out_specs=P("ax")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(P("ax"), P()),
+                               out_specs=P("ax"), check_vma=False))
     args = (jax.ShapeDtypeStruct((s * 4, 8), jnp.float32),
             jax.ShapeDtypeStruct((4, 8), jnp.float32))
     return SPMDProgram(fn=fn, args=args, arg_names=("x", "y"),

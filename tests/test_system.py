@@ -117,3 +117,67 @@ def test_sequential_and_batch_search_agree(index, dataset):
         for a, b in zip(f_batch, f_np)
     ])
     assert agree > 0.8, f"batch/np agreement {agree}"
+
+
+# ---------------------------------------------------- entry reachability ---
+
+def _two_cycles():
+    """Points 0-4 and 5-9 on two separate directed cycles; 4 slots/row."""
+    g = np.full((10, 4), -1, np.int32)
+    for i in range(5):
+        g[i, 0] = (i + 1) % 5
+        g[5 + i, 0] = 5 + (i + 1) % 5
+    d = np.where(g >= 0, 1.0, np.inf).astype(np.float32)
+    x = np.arange(10, dtype=np.float32)[:, None] * np.ones((1, 3), np.float32)
+    return g, d, x
+
+
+def test_connect_from_start_links_every_component():
+    from repro.core.pipnn import _reachable, connect_from_start
+
+    g, d, x = _two_cycles()
+    assert _reachable(g, [0]).sum() == 5
+    g2, d2, added = connect_from_start(g, d, x, 0, "l2")
+    assert added == 1 and _reachable(g2, [0]).all()
+    assert (g2[0] == 5).any(), "the entry point links the other component"
+    slot = int(np.flatnonzero(g2[0] == 5)[0])
+    assert d2[0, slot] == np.float32(3 * 25.0)      # squared l2, 0 -> 5
+    assert (g == _two_cycles()[0]).all(), "inputs are not modified"
+    g3, d3, again = connect_from_start(g2, d2, x, 0, "l2")
+    assert again == 0 and (g3 == g2).all() and (d3 == d2).all()
+
+
+def test_connect_from_start_full_entry_row_links_nearest_reached():
+    """With no free slot at the entry point the link comes from the
+    reached point nearest the component's root, into a free slot."""
+    from repro.core.pipnn import _reachable, connect_from_start
+
+    g, d, x = _two_cycles()
+    g[0] = [1, 2, 3, 4]
+    d[0] = 1.0
+    g2, _, added = connect_from_start(g, d, x, 0, "l2")
+    assert added == 1 and _reachable(g2, [0]).all()
+    assert (g2[4] == 5).any(), "point 4 is the reached point nearest 5"
+
+
+def test_build_on_separated_clusters_reaches_every_point():
+    """More leaders per cluster than the RBC fanout: no leaf spans two
+    clusters, so the raw graph splits per cluster.  The built index must
+    still reach every point from its entry point, and search finds the
+    true neighbors in every cluster."""
+    from repro.core.pipnn import _reachable
+    from repro.data.pipeline import (VectorPipelineConfig, make_queries,
+                                     make_vectors)
+
+    cfg = VectorPipelineConfig(n=3000, dim=16, n_clusters=4,
+                               cluster_scale=20.0, seed=5)
+    x, q = make_vectors(cfg), make_queries(cfg, 100)
+    p = PiPNNParams(rbc=RBCParams(c_max=128, c_min=16, p_samp=0.05,
+                                  fanout=(2, 1)),
+                    leaf=LeafParams(k=2), max_deg=16, seed=0)
+    idx = pipnn.build(x, p)
+    assert idx.stats["connect_edges"] >= 3
+    assert _reachable(idx.graph, [idx.start]).all()
+    # without the links recall stays near the entry cluster's share (~0.25)
+    found = pipnn.search(idx, x, q, k=10, beam=64)
+    assert recall_at_k(found, brute_force_knn(x, q, 10), 10) > 0.9
