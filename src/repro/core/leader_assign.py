@@ -30,6 +30,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.metrics import GEMM_PRECISION
 from repro.kernels.topk import topf
 
 INF = jnp.float32(jnp.inf)
@@ -42,7 +43,8 @@ def leader_dists(points: jax.Array, leaders: jax.Array,
     """Dissimilarity matrix [..., n, l] between ``points`` [..., n, d] and
     ``leaders`` [..., l, d] via the GEMM expansion (batched over leading
     dims).  Mirrors ``rbc._pairwise_np`` term for term."""
-    ip = jnp.einsum("...nd,...ld->...nl", points, leaders)
+    ip = jnp.einsum("...nd,...ld->...nl", points, leaders,
+                    precision=GEMM_PRECISION)
     if metric == "mips":
         return -ip
     if metric == "cosine":

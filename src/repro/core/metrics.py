@@ -17,6 +17,13 @@ Metric = Literal["l2", "mips", "cosine"]
 
 VALID_METRICS = ("l2", "mips", "cosine")
 
+# Precision of every f32 distance GEMM on device.  The TPU's default runs
+# an f32 matmul as one bf16 pass (8-bit mantissa), which reorders near
+# neighbours: with it the leaf k-NN and leader assignment of a 262,144 x
+# 128 Gaussian-mixture build lost recall@10 0.979 -> 0.916 at beam 128.
+# HIGHEST keeps f32 semantics (the host numpy mirrors' and CPU XLA's).
+GEMM_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _check(metric: str) -> None:
     if metric not in VALID_METRICS:
@@ -31,7 +38,7 @@ def pairwise(a: jax.Array, b: jax.Array, metric: Metric = "l2") -> jax.Array:
     Sec. 4.2 / Supplement A.4 — Eigen on CPU, the MXU here).
     """
     _check(metric)
-    ip = a @ b.T  # [n, m] — the GEMM
+    ip = jnp.matmul(a, b.T, precision=GEMM_PRECISION)  # [n, m] — the GEMM
     if metric == "mips":
         return -ip
     if metric == "cosine":

@@ -18,6 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.metrics import GEMM_PRECISION
+
 MAX_BITS = 16
 
 _POW2 = 2 ** jnp.arange(MAX_BITS, dtype=jnp.int32)  # bit i -> weight 2^i
@@ -36,7 +38,7 @@ def sketch(x: jax.Array, hyperplanes: jax.Array) -> jax.Array:
     One GEMM over the whole dataset; the only place the full-dimensional
     vectors are touched by the hashing machinery.
     """
-    return x @ hyperplanes.T
+    return jnp.matmul(x, hyperplanes.T, precision=GEMM_PRECISION)
 
 
 def hash_from_sketches(cand_sketch: jax.Array, point_sketch: jax.Array) -> jax.Array:

@@ -18,7 +18,8 @@ def pairwise_distance_ref(
     """
     a32 = a.astype(jnp.float32)
     b32 = b.astype(jnp.float32)
-    ip = jnp.einsum("bmd,bnd->bmn", a32, b32)
+    ip = jnp.einsum("bmd,bnd->bmn", a32, b32,
+                    precision=jax.lax.Precision.HIGHEST)
     if metric == "mips":
         return -ip
     if metric == "cosine":
@@ -260,7 +261,9 @@ def sketch_hash_ref(
 
     (Bit packing happens per-edge; the kernel fuses the GEMM + padding.)
     """
-    return (x.astype(jnp.float32) @ hyperplanes.astype(jnp.float32).T)
+    return jnp.matmul(x.astype(jnp.float32),
+                      hyperplanes.astype(jnp.float32).T,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def edge_hashes_ref(src_sketch: jax.Array, dst_sketch: jax.Array) -> jax.Array:

@@ -45,6 +45,7 @@ from repro.core import sketch as _sketch
 from repro.core.hashprune import (INVALID_ID, Reservoir, merge_flat_edges,
                                   merge_segmented_edges, reservoir_init)
 from repro.core.leader_assign import leader_assign
+from repro.core.metrics import GEMM_PRECISION
 from repro.core.robust_prune import prune_reservoir_block
 from repro.distributed.routing import group_by_capacity
 
@@ -175,7 +176,7 @@ def _leaf_pair_dists_neg(vecs: jax.Array, p: DistBuildParams) -> jax.Array:
         n2 = jnp.sum(v * v, axis=-1)
     else:
         v = vecs
-        ip = jnp.einsum("bcd,bed->bce", v, v)
+        ip = jnp.einsum("bcd,bed->bce", v, v, precision=GEMM_PRECISION)
         n2 = jnp.sum(v * v, axis=-1)
     neg = jnp.minimum(2.0 * ip - n2[:, :, None] - n2[:, None, :], 0.0)
     if p.leaf_dtype == "bf16":
@@ -406,7 +407,8 @@ def make_final_prune_step(mesh: Mesh, p: DistBuildParams):
             # d_cc from the routed vectors; the keep/compact/truncate logic
             # is the shared block the host build's final_prune also uses
             ids, dists, vecs = t
-            ip = jnp.einsum("bld,bmd->blm", vecs, vecs)
+            ip = jnp.einsum("bld,bmd->blm", vecs, vecs,
+                            precision=GEMM_PRECISION)
             n2 = jnp.sum(vecs * vecs, axis=-1)
             d_cc = jnp.maximum(
                 n2[:, :, None] + n2[:, None, :] - 2.0 * ip, 0.0)

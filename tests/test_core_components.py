@@ -57,6 +57,26 @@ def test_pairwise_matches_naive(metric):
     np.testing.assert_allclose(got, naive, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("gemm", ["pairwise", "leaf_knn", "leader_dists",
+                                  "sketch"])
+def test_distance_gemms_lower_at_full_precision(gemm):
+    """The build's f32 distance GEMMs ask for HIGHEST precision: at the
+    TPU's default (one bf16 pass) leaf k-NN and leader assignment reorder
+    near neighbours.  CPU XLA computes f32 either way, so only the lowered
+    program shows the request."""
+    from repro.core.leader_assign import leader_dists
+    from repro.core.sketch import sketch
+
+    x = jnp.ones((2, 16, 8), jnp.float32)
+    fn = {"pairwise": lambda x: _metrics.pairwise(x[0], x[1]),
+          "leaf_knn": lambda x: leaf_knn_jax(x, x[..., 0] > 0, k=4),
+          "leader_dists": lambda x: leader_dists(x[0], x[1]),
+          "sketch": lambda x: sketch(x[0], x[1, :4])}[gemm]
+    text = jax.jit(fn).lower(x).as_text()
+    assert "dot_general" in text
+    assert "precision = [HIGHEST, HIGHEST]" in text, text
+
+
 # ------------------------------------------------------------------- RBC ---
 
 def _check_cover(leaves, n, c_max):
