@@ -7,9 +7,9 @@ on a TPU at SIFT1M scale, checked against brute force.
 Deployment: the shapes of ann-benchmarks ``sift-128-euclidean`` (1,000,000
 base vectors, d=128, f32, L2, recall@10 over 1,000 queries), generated
 from ``--seed`` by ``data.pipeline.make_vectors`` / ``make_queries``
-(Gaussian mixture).  The index is built with ``PiPNNParams`` defaults for
-``max_deg``, ``l_max`` and ``hash_bits``, and RBC settings scaled to this
-corpus's clusters (``build_params``).
+(Gaussian mixture).  The index is built with ``PiPNNParams`` defaults
+except RBC's level-0 leader count, set for this corpus's clusters
+(``build_params``).
 
 Phases (one chip):
   1. kernels — every Pallas kernel the TPU path selects, compiled for the
@@ -193,23 +193,22 @@ def make_corpus(n: int, n_queries: int, seed: int):
 
 
 def build_params(seed: int):
-    """The smoke's build settings: ``PiPNNParams`` defaults for max_deg,
-    l_max and hash_bits; RBC with 1,250 level-0 leaders (p_samp 0.00125)
-    and c_max 8192 instead of 1,000 capped leaders and c_max 1024.
+    """The smoke's build settings: ``PiPNNParams`` defaults except RBC's
+    level-0 leader count, 1,250 (p_samp 0.00125, leader_cap 10000) where
+    the defaults cap it at 1,000.
 
     Each of this corpus's 32 clusters is an isotropic 128-d Gaussian of
-    ~31K points, and recall follows how many leaders a cluster gets and
-    how much of it one leaf may hold.  At n=262,144 (CPU, beam 128) the
-    defaults gave recall@10 0.80, and 1,310 leaders with c_max 2048 gave
-    0.985; these settings are that configuration scaled per cluster to
-    n=1M (PERF.md section 4)."""
-    from repro.core.leaf import LeafParams
+    ~31K points, and a 31,250-point single-cluster corpus built with the
+    same per-cluster settings reproduced the 1M chip run's recall@10 to
+    within 0.004 (CPU).  On that proxy at beam 128 these settings gave
+    0.926; leaves of up to 8192 points (c_max 8192) gave 0.894, which is
+    what the chip measured at 1M, and took 3.5x the build time
+    (PERF.md section 4)."""
     from repro.core.pipnn import PiPNNParams
     from repro.core.rbc import RBCParams
 
-    return PiPNNParams(
-        rbc=RBCParams(c_max=8192, p_samp=0.00125, leader_cap=10000),
-        leaf=LeafParams(leaf_chunk=4), seed=seed)
+    return PiPNNParams(rbc=RBCParams(p_samp=0.00125, leader_cap=10000),
+                       seed=seed)
 
 
 def build_index(x: np.ndarray, seed: int):
