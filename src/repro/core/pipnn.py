@@ -55,10 +55,12 @@ Stage 4 (``robust_prune.final_prune``) is device-resident too: a donated
 end, so with ``streaming=True`` the entire Stage 2-4 pipeline performs no
 per-chunk host syncs.
 
-Last, ``connect_from_start`` adds the few edges that make every point
-reachable from the entry point (the medoid): on well-separated clusters
-no leaf spans two clusters, and without them beam search would only ever
-reach the medoid's own cluster.  ``stats["connect_edges"]`` counts them.
+Last, ``link_entry_hubs`` gives the entry point (the medoid) edges to
+points spread over the data, and ``connect_from_start`` adds the few
+edges that make every point reachable from it: on well-separated
+clusters few or no leaves span two clusters, and without them beam
+search would rarely leave the medoid's own cluster.
+``stats["connect_edges"]`` counts the reachability edges.
 
 All paths are bit-identical by HashPrune's mergeability (Theorem 3.1):
 tests assert equal graphs on both metrics, for both the segmented and flat
@@ -402,6 +404,45 @@ def _reachable(graph: np.ndarray, roots, blocked: np.ndarray | None = None
     return new
 
 
+def link_entry_hubs(graph: np.ndarray, dists: np.ndarray | None,
+                    x: np.ndarray, start: int, metric: str
+                    ) -> tuple[np.ndarray, np.ndarray | None]:
+    """Give the entry point edges to up to half a row of hubs spread over
+    the data.
+
+    Beam search starts from ``start`` alone.  On well-separated clusters
+    only a few long edges join them, and a query in another cluster than
+    the entry's spends its beam before it finds one: on a 32-cluster
+    Gaussian mixture (n=262,144, beam 128) queries in the entry's cluster
+    reached recall@10 0.98 and the rest 0.74.  The hubs come from a
+    farthest-point traversal from ``start``, which visits every
+    separated cluster before it revisits one.  They take the entry row's
+    free slots, then its longest edges (rows are sorted by distance), so
+    the entry keeps at least half its own edges; edges an eviction cuts
+    off are restored by ``connect_from_start``.  Returns (graph, dists);
+    the inputs are not modified.
+    """
+    graph = graph.copy()
+    dists = None if dists is None else dists.copy()
+    width = graph.shape[1]
+    hubs = []
+    near = _pairwise_np(x, x[start][None], metric)[:, 0]
+    for _ in range(min(width // 2, len(x) - 1)):
+        hubs.append(int(np.argmax(near)))
+        near = np.minimum(near, _pairwise_np(x, x[hubs[-1]][None],
+                                             metric)[:, 0])
+    row = graph[start]
+    hubs = [h for h in hubs if h != start and h not in row]
+    slots = np.concatenate([np.flatnonzero(row < 0),
+                            np.flatnonzero(row >= 0)[width // 2:][::-1]])
+    for h, slot in zip(hubs, slots):
+        graph[start, slot] = h
+        if dists is not None:
+            dists[start, slot] = _pairwise_np(x[start][None], x[h][None],
+                                              metric)[0, 0]
+    return graph, dists
+
+
 def connect_from_start(graph: np.ndarray, dists: np.ndarray | None,
                        x: np.ndarray, start: int, metric: str
                        ) -> tuple[np.ndarray, np.ndarray | None, int]:
@@ -585,8 +626,10 @@ def build(
     # --- every point reachable from the entry point ------------------------
     t0 = time.perf_counter()
     start = medoid(x, seed=params.seed)
+    graph, dists = link_entry_hubs(np.asarray(graph), np.asarray(dists), x,
+                                   start, params.metric)
     graph, dists, stats["connect_edges"] = connect_from_start(
-        np.asarray(graph), np.asarray(dists), x, start, params.metric)
+        graph, dists, x, start, params.metric)
     timings["connect"] = time.perf_counter() - t0
     timings["total"] = sum(timings.values())
 

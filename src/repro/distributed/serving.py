@@ -323,7 +323,8 @@ class ShardedServingIndex:
         ghost rows so no graph edge is dropped; ``halo=False`` keeps the
         bare induced subgraph (smaller, lower recall).  Each shard's
         entry point is its OWNED member nearest the global entry
-        ``x[start]``, and every row of a shard is made reachable from it
+        ``x[start]``; it gets hub edges (``pipnn.link_entry_hubs``) and
+        every row of a shard is made reachable from it
         (``pipnn.connect_from_start`` on the shard's local graph): the
         edges that link the global graph's components hang off the global
         entry point, which a shard without it does not hold.  ``dtype`` follows the single-device packing:
@@ -333,7 +334,7 @@ class ShardedServingIndex:
         identically in every shard that holds it).
         """
         from repro.core.leader_assign import leader_assign
-        from repro.core.pipnn import connect_from_start
+        from repro.core.pipnn import connect_from_start, link_entry_hubs
         from repro.core.serving import _is_int8
 
         if len(mesh.axis_names) != 1:
@@ -414,9 +415,11 @@ class ShardedServingIndex:
             # happen to land in-shard
             local = np.where(ga >= 0, lookup[np.maximum(ga, 0)], -1)
             if c:
+                local, _ = link_entry_hubs(local.astype(np.int32), None,
+                                           x[ridx], int(starts_local[i]),
+                                           metric)
                 local, _, _ = connect_from_start(
-                    local.astype(np.int32), None, x[ridx],
-                    int(starts_local[i]), metric)
+                    local, None, x[ridx], int(starts_local[i]), metric)
             graph_s[i, :c] = local
             norms_s[i, :c] = norms[ridx]
             pts_s[i, :c] = xp[ridx]

@@ -160,11 +160,46 @@ def test_connect_from_start_full_entry_row_links_nearest_reached():
     assert (g2[4] == 5).any(), "point 4 is the reached point nearest 5"
 
 
+def _blob_labels(cfg, x):
+    centers = np.random.default_rng(cfg.seed).standard_normal(
+        (cfg.n_clusters, cfg.dim)) * cfg.cluster_scale
+    return np.argmin(((x[:, None, :] - centers[None]) ** 2).sum(-1), axis=1)
+
+
+def test_link_entry_hubs_reaches_every_cluster():
+    """The entry point of a graph whose clusters share no edge gets a
+    hub in each other cluster; it keeps at least half its own edges, and
+    the inputs are not modified."""
+    from repro.core.pipnn import link_entry_hubs
+    from repro.data.pipeline import VectorPipelineConfig, make_vectors
+
+    cfg = VectorPipelineConfig(n=800, dim=8, n_clusters=5,
+                               cluster_scale=30.0, seed=3)
+    x = make_vectors(cfg)
+    lab = _blob_labels(cfg, x)
+    width = 8
+    g = np.full((len(x), width), -1, np.int32)
+    for c in range(cfg.n_clusters):      # a full ring per cluster
+        m = np.flatnonzero(lab == c)
+        for j in range(width):
+            g[m, j] = np.roll(m, j + 1)
+    d = np.where(g >= 0, 1.0, np.inf).astype(np.float32)
+    g0, d0 = g.copy(), d.copy()
+    g2, d2 = link_entry_hubs(g, d, x, 0, "l2")
+    assert (g == g0).all() and (d == d0).all()
+    row = g2[0]
+    assert set(lab[row]) == set(range(cfg.n_clusters))
+    assert (row[: width // 2] == g0[0, : width // 2]).all()
+    for slot in np.flatnonzero(lab[row] != lab[0]):
+        assert d2[0, slot] == pytest.approx(
+            ((x[0] - x[row[slot]]) ** 2).sum(), rel=1e-5)
+
+
 def test_build_on_separated_clusters_reaches_every_point():
     """More leaders per cluster than the RBC fanout: no leaf spans two
     clusters, so the raw graph splits per cluster.  The built index must
-    still reach every point from its entry point, and search finds the
-    true neighbors in every cluster."""
+    still reach every point from its entry point, whose row links every
+    cluster, and search finds the true neighbors in every cluster."""
     from repro.core.pipnn import _reachable
     from repro.data.pipeline import (VectorPipelineConfig, make_queries,
                                      make_vectors)
@@ -176,7 +211,9 @@ def test_build_on_separated_clusters_reaches_every_point():
                                   fanout=(2, 1)),
                     leaf=LeafParams(k=2), max_deg=16, seed=0)
     idx = pipnn.build(x, p)
-    assert idx.stats["connect_edges"] >= 3
+    lab = _blob_labels(cfg, x)
+    row = idx.graph[idx.start]
+    assert set(lab[row[row >= 0]]) == set(range(cfg.n_clusters))
     assert _reachable(idx.graph, [idx.start]).all()
     # without the links recall stays near the entry cluster's share (~0.25)
     found = pipnn.search(idx, x, q, k=10, beam=64)
