@@ -298,7 +298,9 @@ def merge_segmented_edges(res_ids, res_hashes, res_dists,
           sort at all.
 
     Bit-identical to ``merge_flat_edges`` (both produce rows sorted by
-    (dist, id) with identical padding), which stays as the oracle.
+    (dist, id) with identical padding), which stays as the oracle.  The
+    two halves are the named scopes ``chunk_sort`` and
+    ``reservoir_merge``, so a trace's ops name the half they belong to.
 
     ``use_pallas`` routes the per-row merge through the
     ``kernels/segmented_merge.py`` kernel (rank-based merge of two sorted
@@ -308,23 +310,25 @@ def merge_segmented_edges(res_ids, res_hashes, res_dists,
     the kernel compiled on TPU and interpreted elsewhere.
     """
     n, l_max = res_ids.shape
-    chunk_res = hashprune_flat(src, dst, hashes, dists,
-                               n_points=n, l_max=l_max)
-    if use_pallas:
-        from repro.kernels.ops import default_interpret
-        from repro.kernels.segmented_merge import merge_sorted_reservoirs
+    with jax.named_scope("chunk_sort"):
+        chunk_res = hashprune_flat(src, dst, hashes, dists,
+                                   n_points=n, l_max=l_max)
+    with jax.named_scope("reservoir_merge"):
+        if use_pallas:
+            from repro.kernels.ops import default_interpret
+            from repro.kernels.segmented_merge import merge_sorted_reservoirs
 
-        if interpret is None:
-            interpret = default_interpret()
-        return merge_sorted_reservoirs(
-            res_ids, res_hashes, res_dists,
-            chunk_res.ids, chunk_res.hashes, chunk_res.dists,
-            interpret=interpret)
-    return hashprune_batch(
-        jnp.concatenate([res_ids, chunk_res.ids], axis=-1),
-        jnp.concatenate([res_hashes, chunk_res.hashes], axis=-1),
-        jnp.concatenate([res_dists, chunk_res.dists], axis=-1),
-        l_max=l_max)
+            if interpret is None:
+                interpret = default_interpret()
+            return merge_sorted_reservoirs(
+                res_ids, res_hashes, res_dists,
+                chunk_res.ids, chunk_res.hashes, chunk_res.dists,
+                interpret=interpret)
+        return hashprune_batch(
+            jnp.concatenate([res_ids, chunk_res.ids], axis=-1),
+            jnp.concatenate([res_hashes, chunk_res.hashes], axis=-1),
+            jnp.concatenate([res_dists, chunk_res.dists], axis=-1),
+            l_max=l_max)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"),

@@ -83,13 +83,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-import time
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import sketch as _sketch
 from repro.core.hashprune import (INVALID_ID, Reservoir, hashprune_flat,
                                   merge_flat_edges, merge_segmented_edges,
@@ -216,8 +216,13 @@ def _make_stream_step(
 ):
     """Compile the per-chunk fused step.
 
-    step(res_ids, res_hashes, res_dists, xj, sketches, ids_chunk)
+    stream_step(res_ids, res_hashes, res_dists, xj, sketches, ids_chunk)
       -> (res_ids', res_hashes', res_dists', n_valid_edges)
+
+    Its stages are named scopes, so a trace's ops name their stage
+    (``tracing.op_scopes``): ``leaf_knn`` (gather, GEMM, top-k, emit),
+    ``edge_hash`` (hashes and padding masks), and the segmented fold's
+    ``chunk_sort`` and ``reservoir_merge``.
 
     ``ids_chunk`` is [stream_chunk, c_max]; the leaf kernel (k-NN or, for
     the ``robust_prune`` method, the all-to-all leaf RobustPrune) runs over
@@ -233,7 +238,7 @@ def _make_stream_step(
     knn = knn_fn or (lambda pts, valid: leaf_knn_jax(
         pts, valid, k=k, metric=metric))
 
-    def step(res_ids, res_hashes, res_dists, xj, sketches, ids_chunk):
+    def stream_step(res_ids, res_hashes, res_dists, xj, sketches, ids_chunk):
         n = res_ids.shape[0]
         s, c = ids_chunk.shape
 
@@ -250,26 +255,28 @@ def _make_stream_step(
         # lax.map (not an unrolled python loop): program size stays constant
         # however large the auto-sized stream chunk grows, and the [C, C]
         # working set stays at the sub_chunk VMEM granularity
-        src, dst, dist = jax.lax.map(
-            block, ids_chunk.reshape(s // sub_chunk, sub_chunk, c))
-        src, dst, dist = src.reshape(-1), dst.reshape(-1), dist.reshape(-1)
-        h = _sketch.edge_hashes_from_ids(
-            sketches, src, dst, use_pallas=use_pallas, interpret=interpret)
-        ok = src >= 0
+        with jax.named_scope("leaf_knn"):
+            src, dst, dist = jax.lax.map(
+                block, ids_chunk.reshape(s // sub_chunk, sub_chunk, c))
+            src, dst, dist = (src.reshape(-1), dst.reshape(-1),
+                              dist.reshape(-1))
+        with jax.named_scope("edge_hash"):
+            h = _sketch.edge_hashes_from_ids(
+                sketches, src, dst, use_pallas=use_pallas,
+                interpret=interpret)
+            ok = src >= 0
+            edges = (jnp.where(ok, src, jnp.int32(n)),
+                     jnp.where(ok, dst, INVALID_ID),
+                     jnp.where(ok, h, 0),
+                     jnp.where(ok, dist, jnp.inf))
+            n_valid = jnp.sum(ok, dtype=jnp.int32)
         fold = merge_flat_edges if merge == "flat" else functools.partial(
             merge_segmented_edges, use_pallas=use_pallas_merge,
             interpret=interpret)
-        merged = fold(
-            res_ids, res_hashes, res_dists,
-            jnp.where(ok, src, jnp.int32(n)),
-            jnp.where(ok, dst, INVALID_ID),
-            jnp.where(ok, h, 0),
-            jnp.where(ok, dist, jnp.inf),
-        )
-        return (merged.ids, merged.hashes, merged.dists,
-                jnp.sum(ok, dtype=jnp.int32))
+        merged = fold(res_ids, res_hashes, res_dists, *edges)
+        return merged.ids, merged.hashes, merged.dists, n_valid
 
-    return jax.jit(step, donate_argnums=(0, 1, 2))
+    return jax.jit(stream_step, donate_argnums=(0, 1, 2))
 
 
 def stream_step_workspace_bytes(
@@ -331,6 +338,27 @@ def _stream_chunk_leaves(
     return -(-s // lc) * lc               # round up to a leaf_chunk multiple
 
 
+# The stream step and its argument shapes as the last streamed build in
+# this process ran it.  It exists only for trace readers, through
+# ``stream_step_text``: a reader holds no index and reaches the program by
+# import alone.  Both go once the trace reduction keeps each device op's
+# own scope (its ``tf_op`` metadata, the same ``op_name`` path).
+_last_stream_step: tuple[Callable, list] | None = None
+
+
+def stream_step_text() -> str | None:
+    """The compiled module text of the stream step at the shapes the last
+    streamed build ran it at, or None before one ran: with
+    ``tracing.op_scopes``, the stage each of its ops in a trace belongs
+    to.  It lowers the step again and takes the executable the build left
+    in the jit cache (it compiles only where that is gone), so call it
+    outside a timed window."""
+    if _last_stream_step is None:
+        return None
+    step, args = _last_stream_step
+    return step.lower(*args).compile().as_text()
+
+
 def _build_reservoir_streaming(
     x: np.ndarray,
     leaves_padded: np.ndarray,
@@ -355,9 +383,14 @@ def _build_reservoir_streaming(
     ids_r, hs_r, ds_r = res.ids, res.hashes, res.dists
     counts = []
     for ids in iter_leaf_id_chunks(leaves_padded, chunk):
-        ids_r, hs_r, ds_r, cnt = step(ids_r, hs_r, ds_r, xj, sketches,
-                                      jnp.asarray(ids))
+        ids_j = jnp.asarray(ids)
+        ids_r, hs_r, ds_r, cnt = step(ids_r, hs_r, ds_r, xj, sketches, ids_j)
         counts.append(cnt)  # device scalar: no per-chunk host sync
+    if counts:
+        global _last_stream_step
+        _last_stream_step = (step, [
+            jax.ShapeDtypeStruct(a.shape, a.dtype)
+            for a in (ids_r, hs_r, ds_r, xj, sketches, ids_j)])
     # actual allocated candidate-edge bytes: the fused step materializes
     # src/dst/hash/dist for every (padded) chunk entry; `chunk` is already
     # capped at the padded leaf count, so this is the real buffer size
@@ -535,21 +568,23 @@ def build(
     # two-level carve (ball_carve_device) with zero host recursion, with
     # "device" the host keeps only the worklist while the per-subproblem
     # math runs jitted, and with "host" it is the original numpy oracle.
-    t0 = time.perf_counter()
-    if leaves is None:
-        rbc = dataclasses.replace(params.rbc, metric=params.metric, seed=params.seed)
-        padded = partition_padded(x, rbc, params.partitioner)
-        stats["partition_execution"] = (
-            resolve_execution(rbc) if params.partitioner == "rbc" else "host")
-    else:
-        padded = leaves_to_padded(leaves, params.rbc.c_max)
-        stats["partition_execution"] = "caller"
-    timings["partition"] = time.perf_counter() - t0
-    sizes = (padded >= 0).sum(axis=1)
-    stats["n_leaves"] = int(padded.shape[0])
-    stats["leaf_size_mean"] = float(sizes.mean()) if len(sizes) else 0.0
-    stats["point_repeat"] = float(sizes.sum() / max(n, 1))
-    stats["pad_ratio"] = float(padded.size / max(sizes.sum(), 1))
+    with tracing.span("pipnn.partition", into=timings,
+                      key="partition") as counts:
+        if leaves is None:
+            rbc = dataclasses.replace(params.rbc, metric=params.metric,
+                                      seed=params.seed)
+            padded = partition_padded(x, rbc, params.partitioner)
+            stats["partition_execution"] = (
+                resolve_execution(rbc) if params.partitioner == "rbc"
+                else "host")
+        else:
+            padded = leaves_to_padded(leaves, params.rbc.c_max)
+            stats["partition_execution"] = "caller"
+        placed = int((padded >= 0).sum())
+        counts.update(n_leaves=int(padded.shape[0]),
+                      point_repeat=placed / max(n, 1),
+                      pad_ratio=padded.size / max(placed, 1))
+    stats.update(counts)
     stats["partition_uncovered"] = n - padded_coverage(padded, n)
 
     import jax.random as jrandom
@@ -566,22 +601,20 @@ def build(
         # --- Stage 2+3 fused: streaming device-resident pipeline ----------
         # one fused loop: the (tiny) sketch GEMM is charged to the
         # hashprune phase, everything else to build_leaves
-        t0 = time.perf_counter()
-        sketches = jax.block_until_ready(
-            _sketch.sketch_jit(jnp.asarray(x), hyperplanes))
-        timings["hashprune"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        res, n_edges, mem = _build_reservoir_streaming(
-            x, padded, sketches, lparams, knn_fn)
-        jax.block_until_ready(res.ids)
-        timings["build_leaves"] = time.perf_counter() - t0
+        with tracing.span("pipnn.sketch", into=timings, key="hashprune"):
+            sketches = jax.block_until_ready(
+                _sketch.sketch_jit(jnp.asarray(x), hyperplanes))
+        with tracing.span("pipnn.stream", into=timings, key="build_leaves"):
+            res, n_edges, mem = _build_reservoir_streaming(
+                x, padded, sketches, lparams, knn_fn)
+            jax.block_until_ready(res.ids)
         stats["n_candidate_edges"] = n_edges
         stats.update(mem)
     else:
         # --- Stage 2: leaf building -> candidate edges (Sec. 4.2) ---------
-        t0 = time.perf_counter()
-        edges = build_leaf_edges(x, padded, leaf, knn_fn=knn_fn)
-        timings["build_leaves"] = time.perf_counter() - t0
+        with tracing.span("pipnn.leaf_edges", into=timings,
+                          key="build_leaves"):
+            edges = build_leaf_edges(x, padded, leaf, knn_fn=knn_fn)
         stats["n_candidate_edges"] = int(edges.valid().sum())
         # the host EdgeList carries no hash field (12 B/edge); Stage 3 then
         # materializes src/dst/hash/dist device arrays for ALL edges at once
@@ -592,45 +625,50 @@ def build(
         stats["peak_edge_bytes"] = int(edges.src.size) * _EDGE_BYTES
 
         # --- Stage 3: HashPrune (Sec. 3) ----------------------------------
-        t0 = time.perf_counter()
-        use_pallas, _, interpret = _resolve_pallas(params)
-        sketches = np.asarray(_sketch.sketch_jit(jnp.asarray(x), hyperplanes))
-        hashes = _hash_edges(edges, sketches, use_pallas=use_pallas,
-                             interpret=interpret)
-        src = np.where(edges.src >= 0, edges.src, n).astype(np.int32)
-        dst = np.where(edges.src >= 0, edges.dst, INVALID_ID).astype(np.int32)
-        dist = np.where(edges.src >= 0, edges.dist, np.inf).astype(np.float32)
-        res = hashprune_flat(
-            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(hashes),
-            jnp.asarray(dist), n_points=n, l_max=params.l_max,
-        )
-        timings["hashprune"] = time.perf_counter() - t0
+        with tracing.span("pipnn.hashprune", into=timings, key="hashprune"):
+            use_pallas, _, interpret = _resolve_pallas(params)
+            sketches = np.asarray(
+                _sketch.sketch_jit(jnp.asarray(x), hyperplanes))
+            hashes = _hash_edges(edges, sketches, use_pallas=use_pallas,
+                                 interpret=interpret)
+            src = np.where(edges.src >= 0, edges.src, n).astype(np.int32)
+            dst = np.where(edges.src >= 0, edges.dst,
+                           INVALID_ID).astype(np.int32)
+            dist = np.where(edges.src >= 0, edges.dist,
+                            np.inf).astype(np.float32)
+            res = hashprune_flat(
+                jnp.asarray(src), jnp.asarray(dst), jnp.asarray(hashes),
+                jnp.asarray(dist), n_points=n, l_max=params.l_max,
+            )
 
     # --- Stage 4: final prune (Sec. 4.3) -----------------------------------
-    t0 = time.perf_counter()
-    if params.final_prune:
-        graph, dists = final_prune(
-            x, res, alpha=params.effective_alpha(), max_deg=params.max_deg,
-            metric=params.metric,
-        )
-    else:
-        ids = np.asarray(res.ids)[:, : params.max_deg]
-        ds = np.asarray(res.dists)[:, : params.max_deg]
-        if ids.shape[1] < params.max_deg:
-            pad = params.max_deg - ids.shape[1]
-            ids = np.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-            ds = np.pad(ds, ((0, 0), (0, pad)), constant_values=np.inf)
-        graph, dists = ids, ds
-    timings["final_prune"] = time.perf_counter() - t0
+    with tracing.span("pipnn.final_prune", into=timings, key="final_prune"):
+        if params.final_prune:
+            graph, dists = final_prune(
+                x, res, alpha=params.effective_alpha(),
+                max_deg=params.max_deg, metric=params.metric,
+            )
+        else:
+            ids = np.asarray(res.ids)[:, : params.max_deg]
+            ds = np.asarray(res.dists)[:, : params.max_deg]
+            if ids.shape[1] < params.max_deg:
+                pad = params.max_deg - ids.shape[1]
+                ids = np.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
+                ds = np.pad(ds, ((0, 0), (0, pad)), constant_values=np.inf)
+            graph, dists = ids, ds
 
     # --- every point reachable from the entry point ------------------------
-    t0 = time.perf_counter()
-    start = medoid(x, seed=params.seed)
-    graph, dists = link_entry_hubs(np.asarray(graph), np.asarray(dists), x,
-                                   start, params.metric)
-    graph, dists, stats["connect_edges"] = connect_from_start(
-        graph, dists, x, start, params.metric)
-    timings["connect"] = time.perf_counter() - t0
+    # both spans add into timings["connect"]
+    with tracing.span("pipnn.link_entry_hubs", into=timings, key="connect"):
+        start = medoid(x, seed=params.seed)
+        graph, dists = link_entry_hubs(np.asarray(graph), np.asarray(dists),
+                                       x, start, params.metric)
+    with tracing.span("pipnn.connect_from_start", into=timings,
+                      key="connect") as counts:
+        graph, dists, added = connect_from_start(
+            graph, dists, x, start, params.metric)
+        counts["connect_edges"] = added
+    stats["connect_edges"] = added
     timings["total"] = sum(timings.values())
 
     return PiPNNIndex(

@@ -61,6 +61,7 @@ from typing import Callable, Literal, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core import metrics as _metrics
 
 
@@ -179,7 +180,7 @@ def _next_pow2(v: int) -> int:
 def _make_carve_step(f: int, metric: str, sub: int):
     """Compile the fixed-shape per-subproblem carve step.
 
-    step(xj, idx_pad, lead_pad, m, n_lead) -> (order, starts) where xj is
+    carve_step(xj, idx_pad, lead_pad, m, n_lead) -> (order, starts) where xj is
     the device-resident dataset, idx_pad [R] / lead_pad [L] are padded
     point/leader index blocks (R, L powers of two — shape specialization
     stays logarithmic in n), and m / n_lead are the true counts as traced
@@ -192,7 +193,7 @@ def _make_carve_step(f: int, metric: str, sub: int):
 
     from repro.core.leader_assign import leader_assign
 
-    def step(xj, idx_pad, lead_pad, m, n_lead):
+    def carve_step(xj, idx_pad, lead_pad, m, n_lead):
         r = idx_pad.shape[0]
         l = lead_pad.shape[0]
         leaders = xj[lead_pad]                                  # [L, d]
@@ -214,7 +215,7 @@ def _make_carve_step(f: int, metric: str, sub: int):
             key[order], jnp.arange(l + 1, dtype=jnp.int32)).astype(jnp.int32)
         return order, starts
 
-    return jax.jit(step)
+    return jax.jit(carve_step)
 
 
 def _assign_device(x, idx, leader_pos, f, metric, ctx):
@@ -244,41 +245,49 @@ def _carve_worklist(
 ) -> list[np.ndarray]:
     """Algorithm 5's recursion as an explicit worklist, shared by the host
     and device assignment backends (identical RNG stream consumption, so
-    both produce identical leaves when the assignments agree)."""
+    both produce identical leaves when the assignments agree).
+
+    Spans: ``rbc.worklist`` around the whole and ``rbc.assign`` around
+    each assignment, its copy back to the host included; the worklist's
+    own host time is the first less the second."""
     rng = np.random.default_rng(params.seed if seed is None else seed)
     n = x.shape[0]
     leaves: list[np.ndarray] = []
     # worklist of (point-index-array, depth)
     stack: list[tuple[np.ndarray, int]] = [(np.arange(n, dtype=np.int64), 0)]
-    while stack:
-        idx, depth = stack.pop()
-        if len(idx) <= params.c_max:
-            leaves.append(idx)
-            continue
-        n_leaders = int(
-            np.clip(round(params.p_samp * len(idx)), 2, params.leader_cap)
-        )
-        leader_pos = rng.choice(len(idx), size=n_leaders, replace=False)
-        f = min(params.fanout_at(depth), n_leaders)
-        order, starts = assign_fn(x, idx, leader_pos, f, params.metric, ctx)
-        buckets: list[np.ndarray] = []
-        for s, e in zip(starts[:-1], starts[1:]):
-            if e > s:
-                buckets.append(idx[order[s:e] // f])
-        buckets = _merge_small(buckets, params.c_min, params.c_max, rng)
-        for b in buckets:
-            if len(b) <= params.c_max:
-                leaves.append(b)
-            elif len(b) == len(idx):
-                # no progress (duplicate-heavy data: every point assigned
-                # to one leader) — the bucket equals the parent and would
-                # recurse forever; force-split by permutation halves
-                perm = rng.permutation(len(b))
-                half = len(b) // 2
-                stack.append((b[perm[:half]], depth + 1))
-                stack.append((b[perm[half:]], depth + 1))
-            else:
-                stack.append((b, depth + 1))
+    with tracing.span("rbc.worklist"):
+        while stack:
+            idx, depth = stack.pop()
+            if len(idx) <= params.c_max:
+                leaves.append(idx)
+                continue
+            n_leaders = int(
+                np.clip(round(params.p_samp * len(idx)), 2, params.leader_cap)
+            )
+            leader_pos = rng.choice(len(idx), size=n_leaders, replace=False)
+            f = min(params.fanout_at(depth), n_leaders)
+            with tracing.span("rbc.assign"):
+                order, starts = assign_fn(x, idx, leader_pos, f,
+                                          params.metric, ctx)
+            buckets: list[np.ndarray] = []
+            for s, e in zip(starts[:-1], starts[1:]):
+                if e > s:
+                    buckets.append(idx[order[s:e] // f])
+            buckets = _merge_small(buckets, params.c_min, params.c_max, rng)
+            for b in buckets:
+                if len(b) <= params.c_max:
+                    leaves.append(b)
+                elif len(b) == len(idx):
+                    # no progress (duplicate-heavy data: every point
+                    # assigned to one leader) — the bucket equals the
+                    # parent and would recurse forever; force-split by
+                    # permutation halves
+                    perm = rng.permutation(len(b))
+                    half = len(b) // 2
+                    stack.append((b[perm[:half]], depth + 1))
+                    stack.append((b[perm[half:]], depth + 1))
+                else:
+                    stack.append((b, depth + 1))
     return leaves
 
 
@@ -381,7 +390,7 @@ def _make_static_carve(n_pad: int, l0: int, f0: int, f0r: int, cap_b: int,
             jnp.arange(e, dtype=jnp.uint32) * jnp.uint32(2654435761))
         return [a[perm] for a in arrs]
 
-    def step(xj, lead0_idx, m):
+    def static_carve_step(xj, lead0_idx, m):
         leaders0 = xj[lead0_idx]                               # [l0, d]
         pid = jnp.arange(n_pad, dtype=jnp.int32)
 
@@ -450,7 +459,7 @@ def _make_static_carve(n_pad: int, l0: int, f0: int, f0r: int, cap_b: int,
             [jnp.repeat(bpid.reshape(-1), f1)], shuffle=True)
         return jnp.where(leaf_ok, leaf_ids, -1)                # [n_leaf, c_max]
 
-    return jax.jit(step)
+    return jax.jit(static_carve_step)
 
 
 def carve_workspace_bytes(n_pad: int, d: int, l0: int, f0r: int, cap_b: int,

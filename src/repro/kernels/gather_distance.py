@@ -227,11 +227,13 @@ def _gather_ip_kernel(ids_ref, q_ref, pts_ref, o_ref, buf, sub_ref, sem, *,
 
 
 def _gather_ip(points, queries, nbr_ids, *, hbm: bool, tq: int,
-               interpret: bool) -> jax.Array:
+               interpret: bool, name: str) -> jax.Array:
     """``ip[q, c] = <queries[q], points[max(nbr_ids[q, c], 0)]>`` [Q, C]:
     f32 for float points (f32/bf16), exact int32 for int8 points with
     int32 (quantized) queries.  ``hbm`` leaves the points in HBM
-    (``MemorySpace.ANY``); otherwise the whole block is VMEM-resident."""
+    (``MemorySpace.ANY``); otherwise the whole block is VMEM-resident.
+    ``name`` names the kernel's custom call, and so its ops in a trace,
+    whatever the function that calls it is named."""
     nq, c = nbr_ids.shape
     pack = 4 // points.dtype.itemsize
     if pack > 1:
@@ -264,6 +266,7 @@ def _gather_ip(points, queries, nbr_ids, *, hbm: bool, tq: int,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name=name,
     )(nbr_ids, queries, points)
     return jnp.swapaxes(out, 0, 1).reshape(qp, cp)[:nq, :c]
 
@@ -294,7 +297,9 @@ def _int8_distance(points, scales, norms, queries, q_norms, nbr_ids, *,
         raise TypeError("the int8 gather-distance kernels expect int8 points")
     q8, sq = _ref.quantize_symmetric(queries.astype(jnp.float32))
     ip = _gather_ip(points, q8.astype(jnp.int32), nbr_ids, hbm=hbm, tq=tq,
-                    interpret=interpret)
+                    interpret=interpret,
+                    name="gather_distance_int8_hbm" if hbm
+                    else "gather_distance_int8")
     safe = jnp.maximum(nbr_ids, 0)
     sg = scales.astype(jnp.float32)[safe]
     n2 = norms.astype(jnp.float32)[safe]
@@ -336,7 +341,7 @@ def gather_distance(
     if 0 in nbr_ids.shape:
         return _empty(nbr_ids)
     ip = _gather_ip(points, queries.astype(jnp.float32), nbr_ids, hbm=False,
-                    tq=tq, interpret=interpret)
+                    tq=tq, interpret=interpret, name="gather_distance")
     return _f32_distance(ip, norms, queries, nbr_ids, metric)
 
 
@@ -361,7 +366,7 @@ def gather_distance_hbm(
     if 0 in nbr_ids.shape:
         return _empty(nbr_ids)
     ip = _gather_ip(points, queries.astype(jnp.float32), nbr_ids, hbm=True,
-                    tq=tq, interpret=interpret)
+                    tq=tq, interpret=interpret, name="gather_distance_hbm")
     return _f32_distance(ip, norms, queries, nbr_ids, metric)
 
 

@@ -59,6 +59,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro import tracing
 from repro.core.validation import (InvalidQueryError, validate_queries,
                                    validate_search_params)
 from repro.distributed.fault_tolerance import RollingPercentile
@@ -328,32 +329,41 @@ class ServeLoop:
         """Serve one batch: form it from the queue head, screen poison,
         run the two-phase search, adapt the operating point.  Returns a
         Result per request taken off the queue this step (empty when the
-        queue was empty)."""
+        queue was empty).
+
+        Runs in the span ``serve_loop.step``, with the ``batch`` taken
+        off the queue and its ``stragglers`` (rerun in phase 2); each
+        engine dispatch is a ``serve_loop.search`` span inside it."""
         self._steps += 1
-        if self.probe_every and self._steps % self.probe_every == 0:
-            self._probe_tombstones()
-        batch: list[Request] = []
-        while self._queue and len(batch) < self.query_chunk:
-            batch.append(self._queue.popleft())
-        if not batch:
-            return []
-        now = self.clock()
-        results: list[Result] = []
-        live: list[Request] = []
-        for r in batch:
-            if r.deadline is not None and now >= r.deadline:
-                self.counters["timeout"] += 1
-                results.append(Result(r.rid, None, error="timeout",
-                                      latency=now - r.enqueued_at))
-            elif not np.isfinite(r.query).all():
-                self.counters["invalid"] += 1
-                results.append(Result(r.rid, None, error="invalid:nan_inf",
-                                      latency=now - r.enqueued_at))
-            else:
-                live.append(r)
-        if live:
-            results.extend(self._serve(live))
-        self._adapt()
+        with tracing.span("serve_loop.step") as counts:
+            if self.probe_every and self._steps % self.probe_every == 0:
+                self._probe_tombstones()
+            batch: list[Request] = []
+            while self._queue and len(batch) < self.query_chunk:
+                batch.append(self._queue.popleft())
+            if not batch:
+                return []
+            now = self.clock()
+            results: list[Result] = []
+            live: list[Request] = []
+            for r in batch:
+                if r.deadline is not None and now >= r.deadline:
+                    self.counters["timeout"] += 1
+                    results.append(Result(r.rid, None, error="timeout",
+                                          latency=now - r.enqueued_at))
+                elif not np.isfinite(r.query).all():
+                    self.counters["invalid"] += 1
+                    results.append(Result(r.rid, None,
+                                          error="invalid:nan_inf",
+                                          latency=now - r.enqueued_at))
+                else:
+                    live.append(r)
+            reruns = self.counters["rerun_phase2"]
+            if live:
+                results.extend(self._serve(live))
+            counts.update(batch=len(batch),
+                          stragglers=self.counters["rerun_phase2"] - reruns)
+            self._adapt()
         return results
 
     def run_until_drained(self, *, max_steps: int = 10**6) -> list[Result]:
@@ -372,14 +382,16 @@ class ServeLoop:
     def _search(self, queries: np.ndarray, *, iters: int, chunk: int):
         """One engine dispatch with shard-failure survival: an exception
         carrying ``.shard`` tombstones that shard and retries the SAME
-        batch against the survivors (bounded by ``max_retries``)."""
+        batch against the survivors (bounded by ``max_retries``).  Each
+        attempt is a ``serve_loop.search`` span."""
         op = self.operating_point
         for attempt in range(self.max_retries + 1):
             try:
-                return self.index.search(
-                    queries, k=self.k, beam=op.beam,
-                    expansions=op.expansions, iters=iters,
-                    query_chunk=chunk, with_stats=True)
+                with tracing.span("serve_loop.search"):
+                    return self.index.search(
+                        queries, k=self.k, beam=op.beam,
+                        expansions=op.expansions, iters=iters,
+                        query_chunk=chunk, with_stats=True)
             except Exception as e:  # noqa: BLE001 — filtered just below
                 shard = getattr(e, "shard", None)
                 if (shard is None or attempt >= self.max_retries

@@ -134,3 +134,21 @@ def test_beam_search_engine_hbm_compiles(one_chip, dtype):
         beam=128, iters=66, metric="l2", expansions=4, early_exit=True,
         kernel_path="hbm", interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gather_kernel_name_is_pinned(one_chip):
+    """The f32 HBM kernel's custom call, and so its ops in a trace, is
+    named ``gather_distance_hbm`` by the kernel itself, whatever the
+    function that calls it is named."""
+    import re
+
+    gd = _gd()
+    s = lambda shape, dt: _sds(one_chip, shape, dt)
+
+    def renamed_caller(p, nn, q, i):
+        return gd.gather_distance_hbm.__wrapped__(p, nn, q, i)
+
+    txt = jax.jit(renamed_caller).lower(
+        s((N_HBM, D), jnp.float32), s((N_HBM,), jnp.float32),
+        s((64, D), jnp.float32), s((64, 256), jnp.int32)).compile().as_text()
+    assert re.search(r"%gather_distance_hbm\.\d+ = \S+ custom-call\(", txt)
