@@ -96,6 +96,61 @@ def iter_leaf_id_chunks(leaves_padded: np.ndarray, chunk: int):
 # Batched leaf distance matrices + k-NN picking (pure-JAX path)
 # ---------------------------------------------------------------------------
 
+def _lex_less(a, b):
+    """Pair ``a`` = (value, index) comes before ``b``: the smaller value,
+    equal values to the lower index."""
+    return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+
+
+def _pick(take_a, a, b):
+    return (jnp.where(take_a, a[0], b[0]), jnp.where(take_a, a[1], b[1]))
+
+
+def _merge_smallest(a, b):
+    """The k first pairs, in order, of two ordered k-lists of pairs.
+
+    Entry j of the merge is the least, over the splits p + q = j + 1, of
+    the later of ``a[p - 1]`` and ``b[q - 1]`` (a missing entry comes
+    first).  With distinct indices the order is total, so this combine is
+    commutative and associative, as a reduction needs.
+    """
+    k = len(a)
+    out = []
+    for j in range(k):
+        best = b[j]
+        for p in range(1, j + 1):
+            later = _pick(_lex_less(b[j - p], a[p - 1]), a[p - 1], b[j - p])
+            best = _pick(_lex_less(later, best), later, best)
+        best = _pick(_lex_less(a[j], best), a[j], best)
+        out.append(best)
+    return tuple(out)
+
+
+def _smallest_k(d: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The k smallest entries of each row of ``d`` [..., C], as
+    ``lax.top_k(-d, k)`` picks them: (dist [..., k], idx [..., k]) in
+    ascending distance, equal distances to the lower index, so a row with
+    fewer than k finite entries yields +inf (at its lowest-index +inf
+    entries) in the missing slots.  ``d`` holds no NaN, and -0.0 ties +0.0
+    (l2 distances are clamped at +0.0).
+
+    One variadic reduction over the last axis whose carry is the best k
+    (value, index) pairs: it reads ``d`` once and sorts nothing, where
+    ``lax.top_k`` lowers to a stable sort of each whole row on the TPU.
+    """
+    c = d.shape[-1]
+    axis = d.ndim - 1
+    idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
+    # each entry enters as a k-list: itself, then k - 1 empty slots that
+    # sort after every column (+inf at the index c)
+    empty = (jnp.full_like(d, jnp.inf), jnp.full(d.shape, c, jnp.int32))
+    init = (jnp.array(jnp.inf, d.dtype), jnp.int32(c))
+    best = jax.lax.reduce(((d, idx),) + (empty,) * (k - 1), (init,) * k,
+                          _merge_smallest, (axis,))
+    return (jnp.stack([v for v, _ in best], -1),
+            jnp.stack([i for _, i in best], -1))
+
+
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def leaf_knn_jax(
     pts: jax.Array,     # [B, C, d] gathered leaf points (pad rows arbitrary)
@@ -114,9 +169,8 @@ def leaf_knn_jax(
     eye = jnp.eye(c, dtype=bool)
     mask = valid[:, None, :] & valid[:, :, None] & ~eye[None]
     d = jnp.where(mask, d, jnp.inf)
-    # top-k smallest: negate for lax.top_k
-    neg, idx = jax.lax.top_k(-d, k)
-    nd = -neg
+    with jax.named_scope("select_k"):
+        nd, idx = _smallest_k(d, k)
     ok = jnp.isfinite(nd)
     return jnp.where(ok, idx, -1), jnp.where(ok, nd, jnp.inf)
 

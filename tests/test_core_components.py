@@ -1,6 +1,7 @@
 """Unit + property tests for RBC partitioning, RobustPrune, leaf building,
 beam search, and metrics."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,12 @@ from repro.core.beam_search import (
     medoid,
     recall_at_k,
 )
-from repro.core.leaf import LeafParams, build_leaf_edges, leaf_knn_jax
+from repro.core.leaf import (
+    LeafParams,
+    _smallest_k,
+    build_leaf_edges,
+    leaf_knn_jax,
+)
 from repro.core.rbc import (
     RBCParams,
     ball_carve,
@@ -182,6 +188,63 @@ def test_leaf_knn_matches_bruteforce():
                 continue
             expect = set(np.argsort(d[i], kind="stable")[:3].tolist())
             assert set(ni[b, i].tolist()) == expect
+
+
+def _top_k_smallest(d, k):
+    """The selection `leaf_knn_jax` made with `lax.top_k` before
+    `_smallest_k`: the reference the reduction must equal bit for bit."""
+    neg, idx = jax.lax.top_k(-d, k)
+    return -neg, idx
+
+
+def _top_k_leaf_knn(pts, valid, k):
+    d = jax.vmap(lambda a: _metrics.pairwise(a, a, "l2"))(pts)
+    c = pts.shape[1]
+    mask = valid[:, None, :] & valid[:, :, None] & ~jnp.eye(c, dtype=bool)[None]
+    nd, idx = _top_k_smallest(jnp.where(mask, d, jnp.inf), k)
+    ok = jnp.isfinite(nd)
+    return jnp.where(ok, idx, -1), jnp.where(ok, nd, jnp.inf)
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 8, 9])
+def test_smallest_k_equals_top_k(k):
+    """Integer-valued points tie often; leaves 1-4 are padded, leaf 5 has
+    one valid point and leaf 6 none, so their rows are all +inf."""
+    rng = np.random.default_rng(k)
+    pts = rng.integers(0, 3, (8, 40, 6)).astype(np.float32)
+    valid = np.ones((8, 40), dtype=bool)
+    for b, n_valid in enumerate([37, 20, 9, k, 1, 0], start=1):
+        valid[b, n_valid:] = False
+    pj, vj = jnp.asarray(pts), jnp.asarray(valid)
+
+    d = jax.vmap(lambda a: _metrics.pairwise(a, a, "l2"))(pj)
+    d = jnp.where(vj[:, None, :] & vj[:, :, None], d, jnp.inf)
+    for got, want in zip(jax.jit(_smallest_k, static_argnums=1)(d, k),
+                         _top_k_smallest(d, k)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    for got, want in zip(leaf_knn_jax(pj, vj, k=k),
+                         _top_k_leaf_knn(pj, vj, k)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_streamed_build_equals_top_k_selection():
+    """The streamed build through `leaf_knn_jax` gives the graph the
+    `lax.top_k` selection gave, plugged in through the `knn_fn` hook."""
+    from repro.core import pipnn
+
+    rng = np.random.default_rng(15)
+    x = rng.integers(-4, 5, (2048, 16)).astype(np.float32)
+    params = pipnn.PiPNNParams(
+        rbc=RBCParams(c_max=128, c_min=16, fanout=(3,)),
+        leaf=LeafParams(k=2, leaf_chunk=4), l_max=32, max_deg=16, seed=3)
+    got = pipnn.build(x, params)
+    want = pipnn.build(x, params, knn_fn=functools.partial(_top_k_leaf_knn,
+                                                           k=2))
+    assert got.stats["streaming"] and want.stats["streaming"]
+    np.testing.assert_array_equal(got.graph, want.graph)
+    np.testing.assert_array_equal(got.dists, want.dists)
+    assert got.start == want.start
 
 
 def test_bidirected_contains_both_directions(data):

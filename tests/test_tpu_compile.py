@@ -4,10 +4,11 @@ Nothing runs: each test lowers and compiles for a described (not
 attached) ``v5e:2x2`` topology, so the chip's compiler refuses here what
 interpret mode cannot see — unaligned slices, unsupported primitives,
 blocks off the (8, 128) tiling — and each compiled program must hold the
-kernel (``tpu_custom_call``).  The topology is described inside a fixture
-(only one process may load the TPU library, so never at import), and the
-persistent compile cache is off around these compiles: an entry written
-for a described chip cannot be read back without one.
+kernel (``tpu_custom_call``); the build's leaf k-NN must hold no sort.
+The topology is described inside a fixture (only one process may load
+the TPU library, so never at import), and the persistent compile cache
+is off around these compiles: an entry written for a described chip
+cannot be read back without one.
 """
 import importlib
 
@@ -152,3 +153,19 @@ def test_gather_kernel_name_is_pinned(one_chip):
         s((N_HBM, D), jnp.float32), s((N_HBM,), jnp.float32),
         s((64, D), jnp.float32), s((64, 256), jnp.int32)).compile().as_text()
     assert re.search(r"%gather_distance_hbm\.\d+ = \S+ custom-call\(", txt)
+
+
+def test_leaf_knn_compiles_without_a_sort(one_chip):
+    """The build's leaf k-NN at the benchmark's shape (sub-batches of 8
+    leaves of 1,024 points, k = 2) picks each row's nearest with a
+    reduction: the compiled program holds no sort, which ``lax.top_k``
+    lowers to on the TPU."""
+    import re
+
+    from repro.core.leaf import leaf_knn_jax
+
+    txt = leaf_knn_jax.lower(
+        _sds(one_chip, (8, 1024, D), jnp.float32),
+        _sds(one_chip, (8, 1024), jnp.bool_), k=2).compile().as_text()
+    assert "select_k" in txt
+    assert not re.search(r"\bsort\(", txt)
